@@ -15,9 +15,10 @@ Two headline claims, written to ``BENCH_pipeline.json``:
     metrics (TrainReport.last_metrics) — wall-clock cannot observe the
     bubble on forced-host-device CPU, where all "devices" share the
     same cores and an idle stage frees nothing.  Wall-clock step times
-    are still recorded for context.  Needs >= 4 host devices (stage x
-    data mesh) — run.py's single-device suite runs this section in a
-    forced-8-device subprocess of this module.
+    are still recorded for context.  Needs >= 4 devices (stage x data
+    mesh) in THIS process; with fewer the bench fails rather than
+    spawning a child (a child could not reach a chip the parent holds).
+    On CPU, run it as a script with ``--devices 8``.
 
   * ``memory_constrained``: a config where DP alone CANNOT fit — the
     fully-replicated residency (tp_mode="dp") exceeds per-chip HBM at
@@ -63,7 +64,6 @@ if __name__ == "__main__":
 
 import json
 import pathlib
-import subprocess
 import time
 
 import jax
@@ -169,27 +169,6 @@ def _bench_bubble(steps: int, reps: int) -> dict:
     }
 
 
-def _bubble_via_subprocess(steps: int, reps: int) -> dict:
-    """run.py's suite is single-device; rerun this module's bubble
-    section under 8 forced host devices and parse its JSON line."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    env["PYTHONPATH"] = str(ROOT / "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_pipeline",
-         "--bubble-json", "--steps", str(steps), "--reps", str(reps)],
-        capture_output=True, text=True, timeout=1200, env=env,
-        cwd=str(ROOT))
-    for line in proc.stdout.splitlines():
-        if line.startswith("BUBBLE "):
-            return json.loads(line[len("BUBBLE "):])
-    raise RuntimeError(f"bubble subprocess failed rc={proc.returncode}\n"
-                       f"stdout:\n{proc.stdout[-2000:]}\n"
-                       f"stderr:\n{proc.stderr[-3000:]}")
-
-
 def _bench_memory_constrained() -> dict:
     """The fit-rescue story: DP-replicated residency bursts per-chip
     HBM; the smallest legal stage partition fits."""
@@ -243,15 +222,12 @@ def run(quick: bool = False) -> dict:
     out = {"config": {"devices": len(jax.devices()), "quick": quick,
                       "stages": STAGES,
                       "model": "tinyllama-1.1b-reduced"}}
-    if len(jax.devices()) >= 2 * STAGES:
-        out["bubble"] = _bench_bubble(steps, reps)
-    else:
-        print("  < 4 host devices: measuring bubble in a forced-8 "
-              "subprocess")
-        out["bubble"] = _bubble_via_subprocess(steps, reps)
-        print(f"  bubble measured: multi "
-              f"{out['bubble']['bubble_multi_measured']:.3f} < gpipe "
-              f"{out['bubble']['bubble_gpipe_measured']:.3f}")
+    if len(jax.devices()) < 2 * STAGES:
+        raise RuntimeError(
+            f"bench_pipeline needs >= {2 * STAGES} devices, found "
+            f"{len(jax.devices())}; on CPU run `python -m "
+            "benchmarks.bench_pipeline --devices 8`")
+    out["bubble"] = _bench_bubble(steps, reps)
     out["memory_constrained"] = _bench_memory_constrained()
     OUT_PATH.write_text(json.dumps(out, indent=2) + "\n")
     print(f"  wrote {OUT_PATH}")
@@ -265,13 +241,5 @@ if __name__ == "__main__":
     ap.add_argument("--devices", type=int, default=None,
                     help="force a virtual host device count (script "
                          "mode only; e.g. 8 for the CI leg)")
-    ap.add_argument("--bubble-json", action="store_true",
-                    help="internal: print the bubble section as one "
-                         "'BUBBLE {...}' line and exit")
-    ap.add_argument("--steps", type=int, default=4)
-    ap.add_argument("--reps", type=int, default=3)
     a = ap.parse_args()
-    if a.bubble_json:
-        print("BUBBLE " + json.dumps(_bench_bubble(a.steps, a.reps)))
-    else:
-        run(quick=a.quick)
+    run(quick=a.quick)

@@ -58,6 +58,7 @@ from repro.cluster.faults import FailureRecord, FaultPlan
 from repro.elastic.engine import ElasticEngine
 from repro.elastic.migrate import JobTrainState
 from repro.elastic.runtime import GroupRuntime, TrainReport
+from repro.kernels.ops import kernel_defaults
 from repro.launch.mesh import device_shares, partition_mesh
 from repro.models import model as M
 
@@ -125,7 +126,8 @@ class ClusterController:
                  concurrency: Optional[str] = None,
                  transition_aware: bool = True,
                  join_timeout: Optional[float] = 900.0,
-                 impl: str = "xla", block_t: int = 8, lr: float = 1e-3,
+                 impl: Optional[str] = None,
+                 block_t: Optional[int] = None, lr: float = 1e-3,
                  lr_fn=None, remat: bool = True,
                  quantize: Optional[str] = None, nano_batches: int = 1,
                  adaptive_nano: bool = False, aimd_max_n: int = 16,
@@ -141,6 +143,9 @@ class ClusterController:
                  stuck_after: Optional[float] = 300.0,
                  startup_grace_s: float = 120.0):
         self.cfg_of = cfg_of
+        # unnamed kernel impl / token tile follow the platform (xla on
+        # CPU: the controller's groups run sharded, which ref cannot)
+        impl, block_t = kernel_defaults(impl, block_t, cpu_impl="xla")
         self.devices = list(devices if devices is not None
                             else jax.devices())
         self.fixed_mesh = fixed_mesh

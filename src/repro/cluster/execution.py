@@ -36,6 +36,7 @@ from repro.configs.registry import ARCH_IDS, get_config
 from repro.cluster.controller import (ClusterController, ModelView,
                                       effective_grad_sync)
 from repro.core import throughput as tp
+from repro.kernels.ops import kernel_defaults
 
 
 def executable_models(max_params: float = 2e9) -> Tuple[str, ...]:
@@ -96,7 +97,8 @@ class ExecutionBackend:
 
     def __init__(self, *, steps_per_measure: int = 2,
                  models: Optional[Sequence[str]] = None,
-                 impl: str = "ref", block_t: int = 8, lr: float = 1e-3,
+                 impl: Optional[str] = None,
+                 block_t: Optional[int] = None, lr: float = 1e-3,
                  remat: bool = True, quantize: Optional[str] = None,
                  mesh=None, data_axis: str = "data",
                  grad_sync: str = "gather", tp_mode: str = "dp",
@@ -111,6 +113,7 @@ class ExecutionBackend:
         self.steps_per_measure = steps_per_measure
         self.models = tuple(models) if models is not None \
             else EXECUTABLE_MODELS
+        impl, block_t = kernel_defaults(impl, block_t)
         # mesh: measure on a real sharded mesh (DESIGN.md §8) so the
         # oracle is validated against distributed execution, not a
         # single-device proxy.  effective_grad_sync falls ref/loop back

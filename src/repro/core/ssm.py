@@ -42,8 +42,10 @@ class SharedSuperModel:
     """One fused group: frozen backbone + K stacked adapters."""
     cfg: ModelConfig
     jobs: List[LoRAJobSpec]
-    impl: str = "ref"            # fused-LoRA kernel impl (ref|pallas|xla|loop)
-    block_t: int = 8             # token tile (128 on real TPU)
+    # fused-LoRA kernel impl (ref|pallas|xla|loop) and token tile; None
+    # = the platform's (ops.kernel_defaults: pallas/128 on TPU)
+    impl: Optional[str] = None
+    block_t: Optional[int] = None
     data_shards: int = 1         # data-parallel degree (DESIGN.md §8):
     #                              row counts pad so every job splits evenly
     #                              over the shards with per-shard tile
@@ -54,7 +56,9 @@ class SharedSuperModel:
     layout: RankLayout = field(init=False)
 
     def __post_init__(self):
+        from repro.kernels.ops import kernel_defaults   # kernels import core
         assert self.jobs, "SSM needs at least one job"
+        self.impl, self.block_t = kernel_defaults(self.impl, self.block_t)
         self.ranks = np.array([j.rank for j in self.jobs], np.int32)
         self.scalings = np.array([j.scaling for j in self.jobs], np.float32)
         # pad EACH job's rank to a small sublane multiple, NOT the token
@@ -275,7 +279,6 @@ class SharedSuperModel:
         VJPs) or psum'ed.  The optimizer then updates replicated state
         identically on every shard.
         """
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.data.pipeline import shard_permutation
 
@@ -414,10 +417,12 @@ class SharedSuperModel:
 
         def stepfn(params, adapters, opt_state, batches):
             b_specs = jax.tree.map(lambda _: batch_spec, batches)
-            fn = shard_map(inner, mesh=mesh,
-                           in_specs=(P(), P(), P(), b_specs, P(row_spec)),
-                           out_specs=(P(), P(), P()),
-                           check_rep=False, auto=auto)
+            fn = jax.shard_map(inner, mesh=mesh,
+                               in_specs=(P(), P(), P(), b_specs,
+                                         P(row_spec)),
+                               out_specs=(P(), P(), P()),
+                               axis_names=frozenset(dp_axes),
+                               check_vma=False)
             return fn(params, adapters, opt_state, batches,
                       jnp.asarray(perm, jnp.int32))
 
@@ -461,7 +466,6 @@ class SharedSuperModel:
         grad_sync="gather" (the kernel VJPs' data-axis collectives run
         congruently on every stage row).
         """
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.data.pipeline import shard_permutation
         from repro.launch.mesh import stage_mesh
@@ -677,11 +681,11 @@ class SharedSuperModel:
             p_specs = pipeline_stage_specs(cfg, params)
             ad_specs = pipeline_stage_specs(cfg, adapters)
             opt_specs = adamw.AdamWState(P(), ad_specs, ad_specs)
-            fn = shard_map(inner, mesh=mesh2,
-                           in_specs=(p_specs, ad_specs, opt_specs,
-                                     b_specs, P(data_axis)),
-                           out_specs=(ad_specs, opt_specs, P()),
-                           check_rep=False)
+            fn = jax.shard_map(inner, mesh=mesh2,
+                               in_specs=(p_specs, ad_specs, opt_specs,
+                                         b_specs, P(data_axis)),
+                               out_specs=(ad_specs, opt_specs, P()),
+                               check_vma=False)
             return fn(params, adapters, opt_state, batches,
                       jnp.asarray(perm, jnp.int32))
 

@@ -30,6 +30,7 @@ from repro.core.lora import pad_rank
 from repro.core.scheduler import AdapterScheduler, SchedulerConfig
 from repro.elastic.migrate import JobTrainState, diff_grouping
 from repro.elastic.runtime import GroupRuntime, TrainReport
+from repro.kernels.ops import kernel_defaults
 from repro.models import model as M
 
 GroupKey = Tuple[str, ...]
@@ -40,7 +41,8 @@ class ElasticEngine:
 
     def __init__(self, cfg: ModelConfig, *, key=None, params=None,
                  scheduler: Optional[AdapterScheduler] = None,
-                 impl: str = "ref", block_t: int = 8, lr: float = 1e-3,
+                 impl: Optional[str] = None,
+                 block_t: Optional[int] = None, lr: float = 1e-3,
                  lr_fn: Optional[Callable] = None, remat: bool = True,
                  quantize: Optional[str] = None,
                  nano_batches: int = 1, adaptive_nano: bool = False,
@@ -56,6 +58,7 @@ class ElasticEngine:
         self.params = params if params is not None else \
             M.init_model(jax.random.fold_in(self._key, 0), cfg)
         self.scheduler = scheduler or AdapterScheduler(cfg)
+        impl, block_t = kernel_defaults(impl, block_t)
         self.block_t = block_t
         self.seed = seed
         # mesh: every group this engine builds runs sharded (DESIGN.md

@@ -28,6 +28,7 @@ from repro.core.nanobatch import AIMDController
 from repro.core.ssm import SharedSuperModel
 from repro.data.pipeline import FusedBatcher, JobStream
 from repro.elastic.migrate import JobTrainState, fuse_states, unfuse_state
+from repro.kernels.ops import kernel_defaults
 from repro.models import quant
 from repro.optim import adamw
 from repro.optim.schedule import constant
@@ -96,7 +97,8 @@ class GroupRuntime:
                  streams: Optional[Sequence[JobStream]] = None,
                  steps_done: Optional[Dict[str, int]] = None,
                  lr: float = 1e-3, lr_fn: Optional[Callable] = None,
-                 impl: str = "ref", block_t: int = 8,
+                 impl: Optional[str] = None,
+                 block_t: Optional[int] = None,
                  nano_batches: int = 1, adaptive_nano: bool = False,
                  aimd_max_n: int = 16, nano_order: str = "job",
                  remat: bool = True, quantize: Optional[str] = None,
@@ -111,6 +113,8 @@ class GroupRuntime:
                  seed: int = 0):
         self.cfg = cfg
         self.specs = list(specs)
+        # unnamed kernel impl / token tile follow the platform
+        impl, block_t = kernel_defaults(impl, block_t)
         # sharded group execution (DESIGN.md §8): fused batch rows shard
         # over the mesh (every axis in tp_mode="dp", the data axis only
         # in tp_mode="auto" where the rest is GSPMD tensor parallelism);
@@ -315,8 +319,8 @@ class GroupRuntime:
         # the ragged layout follows the SSM's per-adapter padding rule —
         # each member keeps its OWN padded width, so this fuse is a
         # copy into per-job segments regardless of the group's max rank
-        probe = SharedSuperModel(cfg, specs, impl=kw.get("impl", "ref"),
-                                 block_t=kw.get("block_t", 8))
+        probe = SharedSuperModel(cfg, specs, impl=kw.get("impl"),
+                                 block_t=kw.get("block_t"))
         adapters, opt_state = fuse_states(cfg, states, probe.layout)
         # carry each member's live stream; only stream-less states (e.g.
         # restored checkpoints) start a fresh one
@@ -336,8 +340,8 @@ class GroupRuntime:
         params/adapters (e.g. restored state) are used when given."""
         if params is None or adapters is None:
             probe = SharedSuperModel(cfg, list(specs),
-                                     impl=kw.get("impl", "ref"),
-                                     block_t=kw.get("block_t", 8))
+                                     impl=kw.get("impl"),
+                                     block_t=kw.get("block_t"))
             p, a = probe.init(key)
             params = params if params is not None else p
             adapters = adapters if adapters is not None else a

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.fused_lora import pallas_call
+
 NEG_BIG = -1e30
 
 
@@ -86,8 +88,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref,
 
 def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True, block_q: int = 128,
-                        block_k: int = 128,
-                        interpret: bool = True) -> jax.Array:
+                        block_k: int = 128) -> jax.Array:
     """q: (BH, Sq, hd); k/v: (BH, Skv, hd) — flat (batch*heads) leading dim
     (GQA callers repeat kv heads; see models/attention._rep_heads).
 
@@ -106,7 +107,7 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
         _flash_fwd_kernel, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k, n_kv=n_kv)
 
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(BH, n_q, n_kv),
         in_specs=[
@@ -121,7 +122,6 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, vd), jnp.float32),
         ],
         out_shape=jax.ShapeDtypeStruct((BH, Sq, vd), q.dtype),
-        interpret=interpret,
     )(q, k, v)
 
 

@@ -16,6 +16,8 @@ TPU adaptation of the paper's Triton kernel (see DESIGN.md §3):
   computed once per token tile (at i_o == 0) and reused from scratch for
   all dout tiles — the VMEM analogue of Triton's shared-memory reuse.
 
+Every launch goes through ``pallas_call`` below: interpreted where the
+program is compiled for CPU (the test suite), compiled Mosaic on TPU.
 Validated in interpret mode on CPU against kernels/ref.py.
 """
 from __future__ import annotations
@@ -26,6 +28,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+def pallas_call(kernel, **kw):
+    """``pl.pallas_call`` whose mode follows the platform the program is
+    compiled for: the Pallas interpreter where it lowers for CPU,
+    compiled Mosaic everywhere else.  ``lax.platform_dependent`` makes
+    the choice at lowering, so one traced step is interpreted in the CPU
+    test suite and compiled when its arrays live on a TPU (an AOT
+    compile for a described TPU from a CPU host included).  This is the
+    only place interpret mode is decided."""
+    interpreted = pl.pallas_call(kernel, interpret=True, **kw)
+    compiled = pl.pallas_call(kernel, **kw)
+
+    def call(*args):
+        return jax.lax.platform_dependent(*args, cpu=interpreted,
+                                          default=compiled)
+    return call
 
 
 def _fit_block(n: int, cap: int) -> int:
@@ -61,8 +80,7 @@ def _fused_lora_kernel(tile_map_ref, ranks_ref, x_ref, a_ref, b_ref,
 
 def fused_lora_pallas(x: jax.Array, A: jax.Array, B: jax.Array,
                       tile_map: jax.Array, ranks: jax.Array,
-                      *, block_t: int = 128, block_o: int = 512,
-                      interpret: bool = True) -> jax.Array:
+                      *, block_t: int = 128, block_o: int = 512) -> jax.Array:
     """x: (T, d_in), A: (K, d_in, r_pad), B: (K, r_pad, d_out),
     tile_map: (T//block_t,) adapter id per token tile.
 
@@ -86,11 +104,10 @@ def fused_lora_pallas(x: jax.Array, A: jax.Array, B: jax.Array,
         out_specs=pl.BlockSpec((block_t, block_o), lambda i, j, tm, rk: (i, j)),
         scratch_shapes=[pltpu.VMEM((block_t, r_pad), jnp.float32)],
     )
-    return pl.pallas_call(
+    return pallas_call(
         _fused_lora_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, d_out), x.dtype),
-        interpret=interpret,
     )(tile_map, ranks, x, A, B)
 
 
@@ -120,8 +137,7 @@ def _grouped_wgrad_kernel(tile_map_ref, x_ref, g_ref, o_ref):
 
 def grouped_wgrad_pallas(x: jax.Array, g: jax.Array, tile_map: jax.Array,
                          num_adapters: int, *, block_t: int = 128,
-                         block_o: int = 512,
-                         interpret: bool = True) -> jax.Array:
+                         block_o: int = 512) -> jax.Array:
     """Segment-aware wgrad: out[k] = Σ_{t: adapter(t)=k} x_tᵀ g_t.
 
     x: (T, d_in), g: (T, d_out), tile_map: (T//block_t,) *sorted* adapter
@@ -151,11 +167,10 @@ def grouped_wgrad_pallas(x: jax.Array, g: jax.Array, tile_map: jax.Array,
         out_specs=pl.BlockSpec((1, d_in, block_o),
                                lambda j, i, tm: (tm[i], 0, j)),
     )
-    out = pl.pallas_call(
+    out = pallas_call(
         _grouped_wgrad_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((K, d_in, d_out), jnp.float32),
-        interpret=interpret,
     )(tile_map, x, g)
     # adapters with zero token tiles are never visited — their output
     # block is uninitialized memory; the true gradient is zero.
@@ -177,8 +192,8 @@ def _dequant_mm_kernel(x_ref, w_ref, s_ref, o_ref):
 
 
 def dequant_matmul_pallas(x: jax.Array, w_q: jax.Array, scale: jax.Array,
-                          *, block_t: int = 128, block_o: int = 512,
-                          interpret: bool = True) -> jax.Array:
+                          *, block_t: int = 128,
+                          block_o: int = 512) -> jax.Array:
     """Fused dequantize-matmul for the quantized frozen backbone.
 
     x: (T, d_in) activations; w_q: (d_in, d_out) int8; scale: (d_out,)
@@ -196,7 +211,7 @@ def dequant_matmul_pallas(x: jax.Array, w_q: jax.Array, scale: jax.Array,
     block_o = _fit_block(d_out, block_o)
     grid = (T // block_t, d_out // block_o)
     s2 = scale.reshape(1, d_out).astype(jnp.float32)
-    return pl.pallas_call(
+    return pallas_call(
         _dequant_mm_kernel,
         grid=grid,
         in_specs=[
@@ -206,7 +221,6 @@ def dequant_matmul_pallas(x: jax.Array, w_q: jax.Array, scale: jax.Array,
         ],
         out_specs=pl.BlockSpec((block_t, block_o), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((T, d_out), x.dtype),
-        interpret=interpret,
     )(x, w_q, s2)
 
 
@@ -218,8 +232,8 @@ def _grouped_mm_kernel(tile_map_ref, x_ref, w_ref, o_ref):
 
 
 def grouped_matmul_pallas(x: jax.Array, W: jax.Array, tile_map: jax.Array,
-                          *, block_t: int = 128, block_o: int = 512,
-                          interpret: bool = True) -> jax.Array:
+                          *, block_t: int = 128,
+                          block_o: int = 512) -> jax.Array:
     """y_t = x_t @ W[adapter(t)] with one adapter per token tile.
     Used for the dx passes of the custom VJP."""
     T, d_in = x.shape
@@ -237,9 +251,8 @@ def grouped_matmul_pallas(x: jax.Array, W: jax.Array, tile_map: jax.Array,
         ],
         out_specs=pl.BlockSpec((block_t, block_o), lambda i, j, tm: (i, j)),
     )
-    return pl.pallas_call(
+    return pallas_call(
         _grouped_mm_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, d_out), x.dtype),
-        interpret=interpret,
     )(tile_map, x, W)

@@ -33,10 +33,12 @@ Contract required by "pallas"/"xla": tokens sorted by adapter id,
 contiguous segments, each segment length a multiple of block_t (the SSM
 batch layout guarantees this — see core/ssm.py).
 
-Interpret mode: kernels default to the Pallas interpreter (CPU CI).  On a
-real TPU backend set ``REPRO_INTERPRET=0`` in the environment, or call
-``set_interpret(False)`` before building any train step — no source edit
-required.
+Interpret mode follows the backend: every Pallas launch lowers to the
+Pallas interpreter where the program is compiled for CPU (the test
+suite) and to compiled Mosaic on TPU (``kernels/fused_lora.pallas_call``
+decides, at lowering).  There is no switch to set.  ``kernel_defaults``
+picks the kernel family and token tile the same way for callers that
+name neither.
 
 Shard-local variants (DESIGN.md §8): under ``shard_map`` over a data
 axis, each device holds a tile-aligned mini fused batch (per-adapter
@@ -53,7 +55,6 @@ strategy lives one level up (core/ssm.py, grad_sync="psum").
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional, Sequence, Tuple
 
 import numpy as np
@@ -66,33 +67,19 @@ from repro.kernels import ragged as rg
 from repro.kernels.ragged import RaggedMeta
 
 
-def _env_interpret() -> bool:
-    return os.environ.get("REPRO_INTERPRET", "1").lower() not in (
-        "0", "false", "no")
-
-
-_INTERPRET = _env_interpret()
-
-
-def set_interpret(flag: bool) -> None:
-    """Flip Pallas interpret mode process-wide (False = compile Mosaic).
-
-    Must be called BEFORE the first train-step build: the flag is baked
-    into traced programs at jit/AOT-compile time, so train steps compiled
-    earlier (GroupRuntime._step_cache, user ``jax.jit`` wrappers) keep
-    the old flag.  Only the custom-VJP closure cache is cleared here —
-    already-compiled executables cannot be reached from this module."""
-    global _INTERPRET
-    _INTERPRET = bool(flag)
-    _make_pallas_fn.cache_clear()
-    _make_pallas_sharded_fn.cache_clear()
-    _make_ragged_pallas_fn.cache_clear()
-    _make_ragged_pallas_sharded_fn.cache_clear()
-    _make_dequant_pallas_fn.cache_clear()
-
-
-def get_interpret() -> bool:
-    return _INTERPRET
+def kernel_defaults(impl: Optional[str] = None,
+                    block_t: Optional[int] = None, *,
+                    cpu_impl: str = "ref") -> Tuple[str, int]:
+    """(impl, block_t) for a caller that names neither.  On TPU: the
+    Pallas kernels over 128-row token tiles.  Elsewhere: *cpu_impl*
+    (each entry point keeps its historical CPU default) over 8-row
+    tiles, which keeps interpret-mode tests fast."""
+    on_tpu = jax.default_backend() == "tpu"
+    if impl is None:
+        impl = "pallas" if on_tpu else cpu_impl
+    if block_t is None:
+        block_t = 128 if on_tpu else 8
+    return impl, int(block_t)
 
 
 def _tile_map(ids: jax.Array, block_t: int) -> jax.Array:
@@ -133,8 +120,11 @@ def _xla_forward(x, A, B, ids, ranks, scalings, equal_segments: bool):
                     preferred_element_type=jnp.float32)
     xa = jnp.where(lane[None, None, :] < ranks[None, :, None],
                    xa, 0.0).astype(x.dtype)
-    y = jnp.einsum("tkr,kro->tko", xa, B,
-                   preferred_element_type=jnp.float32)
+    # f32 operands: products of bf16 values are exact in f32, so this is
+    # the bf16-in/f32-accumulate GEMM, and XLA:CPU has no bf16 x bf16 ->
+    # f32 kernel for this mid-axis batch layout
+    y = jnp.einsum("tkr,kro->tko", xa.astype(jnp.float32),
+                   B.astype(jnp.float32))
     y = y * scalings[None, :, None]
     return jnp.einsum("tko,tk->to", y, onehot.astype(jnp.float32)
                       ).astype(x.dtype)
@@ -353,12 +343,11 @@ def _make_pallas_fn(block_t: int):
     scalings are alpha/r constants that are never trained, so they are
     stop-gradiented (float0 cotangent) — one grouped-mm launch + einsum
     saved per backward."""
-    interpret = _INTERPRET
 
     @jax.custom_vjp
     def f(x, A, B, ids, ranks, scalings):
         y = pk.fused_lora_pallas(x, A, B, _tile_map(ids, block_t), ranks,
-                                 block_t=block_t, interpret=interpret)
+                                 block_t=block_t)
         return (y.astype(jnp.float32) * scalings[ids][:, None]).astype(x.dtype)
 
     def _fwd(x, A, B, ids, ranks, scalings):
@@ -373,22 +362,19 @@ def _make_pallas_fn(block_t: int):
 
         # dx = ((dy_s @ B^T) * mask) @ A^T — two grouped-mm kernel launches
         dxa = pk.grouped_matmul_pallas(dy_s, jnp.swapaxes(B, 1, 2), tm,
-                                       block_t=block_t, interpret=interpret)
+                                       block_t=block_t)
         dxa = ref_impl.rank_mask(dxa.astype(jnp.float32), ids,
                                  ranks).astype(x.dtype)
         dx = pk.grouped_matmul_pallas(dxa, jnp.swapaxes(A, 1, 2), tm,
-                                      block_t=block_t, interpret=interpret)
+                                      block_t=block_t)
 
         # wgrads: segment-aware grouped accumulation (revisiting-output
         # kernels over the sorted token tiles — f32 accumulators)
-        xa = pk.grouped_matmul_pallas(x, A, tm, block_t=block_t,
-                                      interpret=interpret)
+        xa = pk.grouped_matmul_pallas(x, A, tm, block_t=block_t)
         xa = ref_impl.rank_mask(xa.astype(jnp.float32), ids,
                                 ranks).astype(x.dtype)
-        dA = pk.grouped_wgrad_pallas(x, dxa, tm, K, block_t=block_t,
-                                     interpret=interpret)
-        dB = pk.grouped_wgrad_pallas(xa, dy_s, tm, K, block_t=block_t,
-                                     interpret=interpret)
+        dA = pk.grouped_wgrad_pallas(x, dxa, tm, K, block_t=block_t)
+        dB = pk.grouped_wgrad_pallas(xa, dy_s, tm, K, block_t=block_t)
 
         return (dx.astype(x.dtype), dA.astype(A.dtype), dB.astype(B.dtype),
                 _int_zeros(ids), _int_zeros(ranks),
@@ -413,12 +399,11 @@ def _make_pallas_sharded_fn(block_t: int, axis_name: str,
     solo layout, which only the full batch guarantees (``full_batch``);
     a nano-slice's reassembled ids carry zeros in other slices' slots,
     so those drop to the order/value-invariant one-hot wgrads."""
-    interpret = _INTERPRET
 
     @jax.custom_vjp
     def f(x, A, B, ids, ranks, scalings, solo_pos):
         y = pk.fused_lora_pallas(x, A, B, _tile_map(ids, block_t), ranks,
-                                 block_t=block_t, interpret=interpret)
+                                 block_t=block_t)
         return (y.astype(jnp.float32) * scalings[ids][:, None]).astype(x.dtype)
 
     def _fwd(x, A, B, ids, ranks, scalings, solo_pos):
@@ -433,11 +418,11 @@ def _make_pallas_sharded_fn(block_t: int, axis_name: str,
 
         # ---- local: dx (two grouped-mm launches over the local tiles)
         dxa = pk.grouped_matmul_pallas(dy_s, jnp.swapaxes(B, 1, 2), tm,
-                                       block_t=block_t, interpret=interpret)
+                                       block_t=block_t)
         dxa = ref_impl.rank_mask(dxa.astype(jnp.float32), ids,
                                  ranks).astype(x.dtype)
         dx = pk.grouped_matmul_pallas(dxa, jnp.swapaxes(A, 1, 2), tm,
-                                      block_t=block_t, interpret=interpret)
+                                      block_t=block_t)
 
         # ---- global: wgrads from the solo-order full-shape tensors
         xg = gather_solo(x, axis_name, solo_pos, total_tokens)
@@ -446,19 +431,15 @@ def _make_pallas_sharded_fn(block_t: int, axis_name: str,
         if full_batch:
             tmg = _tile_map(idg, block_t)
             gdxa = pk.grouped_matmul_pallas(dyg_s, jnp.swapaxes(B, 1, 2),
-                                            tmg, block_t=block_t,
-                                            interpret=interpret)
+                                            tmg, block_t=block_t)
             gdxa = ref_impl.rank_mask(gdxa.astype(jnp.float32), idg,
                                       ranks).astype(x.dtype)
-            xag = pk.grouped_matmul_pallas(xg, A, tmg, block_t=block_t,
-                                           interpret=interpret)
+            xag = pk.grouped_matmul_pallas(xg, A, tmg, block_t=block_t)
             xag = ref_impl.rank_mask(xag.astype(jnp.float32), idg,
                                      ranks).astype(x.dtype)
-            dA = pk.grouped_wgrad_pallas(xg, gdxa, tmg, K, block_t=block_t,
-                                         interpret=interpret)
+            dA = pk.grouped_wgrad_pallas(xg, gdxa, tmg, K, block_t=block_t)
             dB = pk.grouped_wgrad_pallas(xag, dyg_s, tmg, K,
-                                         block_t=block_t,
-                                         interpret=interpret)
+                                         block_t=block_t)
         else:
             # dyg_s is already scaled — unit scalings avoid double-scaling
             ones = jnp.ones_like(scalings)
@@ -811,12 +792,10 @@ def _make_ragged_pallas_fn(meta: RaggedMeta, block_t: int):
     launches (xa, dxa) + two ragged-wgrad launches (dA, dB) — every
     grid step is an active (token tile, rank tile) pair, so the whole
     backward does true-rank work.  Scalings stop-gradiented (float0)."""
-    interpret = _INTERPRET
 
     @jax.custom_vjp
     def f(x, A, B, ids, scalings):
-        y = rg.ragged_lora_fwd(x, A, B, meta, block_t=block_t,
-                               interpret=interpret)
+        y = rg.ragged_lora_fwd(x, A, B, meta, block_t=block_t)
         return (y * scalings[ids][:, None]).astype(x.dtype)
 
     def _fwd(x, A, B, ids, scalings):
@@ -826,16 +805,13 @@ def _make_ragged_pallas_fn(meta: RaggedMeta, block_t: int):
         x, A, B, ids, scalings = res
         dy_s = (dy.astype(jnp.float32)
                 * scalings[ids][:, None]).astype(dy.dtype)
-        dx = rg.ragged_lora_dgrad(dy_s, A, B, meta, block_t=block_t,
-                                  interpret=interpret)
-        xa = rg.ragged_xa(x, A, meta, block_t=block_t,
-                          interpret=interpret)
-        dxa = rg.ragged_dxa(dy_s, B, meta, block_t=block_t,
-                            interpret=interpret).astype(x.dtype)
-        dA = rg.ragged_wgrad(dxa, x, meta, block_t=block_t,
-                             interpret=interpret)          # (R, d_in)
-        dB = rg.ragged_wgrad(xa, dy_s, meta, block_t=block_t,
-                             interpret=interpret)          # (R, d_out)
+        dx = rg.ragged_lora_dgrad(dy_s, A, B, meta, block_t=block_t)
+        # packed intermediates are rank-major (R, T) — kernels/ragged.py
+        xat = rg.ragged_xa(x, A, meta, block_t=block_t)
+        dxat = rg.ragged_dxa(dy_s, B, meta,
+                             block_t=block_t).astype(x.dtype)
+        dA = rg.ragged_wgrad(dxat, x, meta, block_t=block_t)   # (R, d_in)
+        dB = rg.ragged_wgrad(xat, dy_s, meta, block_t=block_t)  # (R, d_out)
         return (dx.astype(x.dtype), dA.T.astype(A.dtype),
                 dB.astype(B.dtype), _int_zeros(ids),
                 np.zeros(scalings.shape, jax.dtypes.float0))
@@ -857,12 +833,10 @@ def _make_ragged_pallas_sharded_fn(meta_local: RaggedMeta,
     row contributes exact zeros to its segment's accumulator whatever
     segment the static map assigns it — so no dense fallback is needed
     anywhere (the masked pallas path needed one)."""
-    interpret = _INTERPRET
 
     @jax.custom_vjp
     def f(x, A, B, ids, scalings, solo_pos):
-        y = rg.ragged_lora_fwd(x, A, B, meta_local, block_t=block_t,
-                               interpret=interpret)
+        y = rg.ragged_lora_fwd(x, A, B, meta_local, block_t=block_t)
         return (y * scalings[ids][:, None]).astype(x.dtype)
 
     def _fwd(x, A, B, ids, scalings, solo_pos):
@@ -875,20 +849,17 @@ def _make_ragged_pallas_sharded_fn(meta_local: RaggedMeta,
                 * scalings[ids][:, None]).astype(dy.dtype)
 
         # ---- local: dx (one fused ragged dgrad launch)
-        dx = rg.ragged_lora_dgrad(dy_s, A, B, meta_local, block_t=block_t,
-                                  interpret=interpret)
+        dx = rg.ragged_lora_dgrad(dy_s, A, B, meta_local,
+                                  block_t=block_t)
 
         # ---- global: wgrads from the solo-order full-shape tensors
         xg = gather_solo(x, axis_name, solo_pos, total_tokens)
         dyg_s = gather_solo(dy_s, axis_name, solo_pos, total_tokens)
-        xag = rg.ragged_xa(xg, A, meta_solo, block_t=block_t,
-                           interpret=interpret)
-        gdxa = rg.ragged_dxa(dyg_s, B, meta_solo, block_t=block_t,
-                             interpret=interpret).astype(x.dtype)
-        dA = rg.ragged_wgrad(gdxa, xg, meta_solo, block_t=block_t,
-                             interpret=interpret)
-        dB = rg.ragged_wgrad(xag, dyg_s, meta_solo, block_t=block_t,
-                             interpret=interpret)
+        xagt = rg.ragged_xa(xg, A, meta_solo, block_t=block_t)
+        gdxat = rg.ragged_dxa(dyg_s, B, meta_solo,
+                              block_t=block_t).astype(x.dtype)
+        dA = rg.ragged_wgrad(gdxat, xg, meta_solo, block_t=block_t)
+        dB = rg.ragged_wgrad(xagt, dyg_s, meta_solo, block_t=block_t)
         return (dx.astype(x.dtype), dA.T.astype(A.dtype),
                 dB.astype(B.dtype), _int_zeros(ids),
                 np.zeros(scalings.shape, jax.dtypes.float0),
@@ -1060,13 +1031,11 @@ def _make_dequant_pallas_fn(block_t: int, block_o: int):
     launch — dx = (dy * scale) @ q.T, i.e. the same kernel against the
     transposed int8 slab with unit scales (the row scaling moved onto
     the cotangent, still never materializing a dequantized copy)."""
-    interpret = _INTERPRET
 
     @jax.custom_vjp
     def f(x, q, s):
         return pk.dequant_matmul_pallas(x, q, s, block_t=block_t,
-                                        block_o=block_o,
-                                        interpret=interpret)
+                                        block_o=block_o)
 
     def fwd(x, q, s):
         return f(x, q, s), (q, s)
@@ -1077,7 +1046,7 @@ def _make_dequant_pallas_fn(block_t: int, block_o: int):
                * s.astype(jnp.float32)[None, :]).astype(dy.dtype)
         ones = jnp.ones((q.shape[0],), jnp.float32)
         dx = pk.dequant_matmul_pallas(dys, q.T, ones, block_t=block_t,
-                                      block_o=block_o, interpret=interpret)
+                                      block_o=block_o)
         return dx, _int_zeros(q), jnp.zeros_like(s)
 
     f.defvjp(fwd, bwd)

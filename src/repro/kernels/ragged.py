@@ -23,9 +23,17 @@ Mechanics
   * Wgrads flatten in (adapter, rank tile, token tile) order instead —
     token tiles innermost — so each packed (r_blk, block_o) gradient
     block accumulates over its segment's consecutive visits.
-  * The rank-tile width is ``layout.multiple`` (a sublane multiple; 128
-    on real TPU lanes), so every per-adapter padded width is whole rank
-    tiles by construction.
+  * The rank-tile width is ``layout.multiple`` (16 with 128-row token
+    tiles), so every per-adapter padded width is whole rank tiles by
+    construction.
+  * Rank tiles sit on the SECOND-TO-LAST axis of every block.  Mosaic
+    needs a block's last two dims to be multiples of (8, 128) or the
+    whole array dim; a 16-wide rank tile meets the sublane rule (8)
+    and never the lane rule (128).  So the wrappers hand the kernels
+    ``A`` transposed, ``(R, d_in)`` (a small XLA transpose of the
+    adapter, not of activations), and the packed intermediates
+    ``xa``/``dxa`` are written rank-major, ``(R, T)``.  The packed
+    ``(d, R)/(R, d)`` storage is unchanged.
 
 Validated in interpret mode on CPU against kernels/ref.py (see
 tests/test_ragged_kernels.py: bit/tol-exact vs the masked max-rank
@@ -44,7 +52,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.lora import RankLayout
-from repro.kernels.fused_lora import _fit_block
+from repro.kernels.fused_lora import _fit_block, pallas_call
 
 
 @dataclass(frozen=True)
@@ -132,27 +140,35 @@ def _prefetch(meta_arrays) -> list:
     return [jnp.asarray(a) for a in meta_arrays]
 
 
+def _nt(a, b):
+    """a · bᵀ (contract the last dims), f32 accumulation."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _rank_mask(v, n_lanes, axis: int):
+    lane = jax.lax.broadcasted_iota(jnp.int32, v.shape, axis)
+    return jnp.where(lane < n_lanes, v, 0.0)
+
+
 # ------------------------------------------------------------------ fwd
 def _fwd_kernel(tile_ref, rt_ref, first_ref, lanes_ref,
-                x_ref, a_ref, b_ref, o_ref):
+                x_ref, at_ref, b_ref, o_ref):
     f = pl.program_id(1)
 
     @pl.when(first_ref[f] == 1)
     def _zero():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...]
-    xa = jnp.dot(x, a_ref[...], preferred_element_type=jnp.float32)
-    lane = jax.lax.broadcasted_iota(jnp.int32, xa.shape, 1)
-    xa = jnp.where(lane < lanes_ref[f], xa, 0.0).astype(x_ref.dtype)
+    xa = _nt(x_ref[...], at_ref[...])                  # (block_t, r_blk)
+    xa = _rank_mask(xa, lanes_ref[f], 1).astype(x_ref.dtype)
     o_ref[...] += jnp.dot(xa, b_ref[...],
                           preferred_element_type=jnp.float32)
 
 
 def ragged_lora_fwd(x: jax.Array, A: jax.Array, B: jax.Array,
                     meta: RaggedMeta, *, block_t: int = 128,
-                    block_o: int = 512,
-                    interpret: bool = True) -> jax.Array:
+                    block_o: int = 512) -> jax.Array:
     """x: (T, d_in), A: (d_in, R), B: (R, d_out) packed ragged.
 
     Returns (T, d_out) *unscaled* LoRA output in f32 (caller scales and
@@ -173,47 +189,39 @@ def ragged_lora_fwd(x: jax.Array, A: jax.Array, B: jax.Array,
         in_specs=[
             pl.BlockSpec((block_t, d_in),
                          lambda j, f, tm, rt, fi, ln: (tm[f], 0)),
-            pl.BlockSpec((d_in, meta.r_blk),
-                         lambda j, f, tm, rt, fi, ln: (0, rt[f])),
+            pl.BlockSpec((meta.r_blk, d_in),
+                         lambda j, f, tm, rt, fi, ln: (rt[f], 0)),
             pl.BlockSpec((meta.r_blk, block_o),
                          lambda j, f, tm, rt, fi, ln: (rt[f], j)),
         ],
         out_specs=pl.BlockSpec((block_t, block_o),
                                lambda j, f, tm, rt, fi, ln: (tm[f], j)),
     )
-    return pl.pallas_call(
+    return pallas_call(
         _fwd_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, d_out), jnp.float32),
-        interpret=interpret,
-    )(*_prefetch((tile, rtile, first, lanes)), x, A, B)
+    )(*_prefetch((tile, rtile, first, lanes)), x, A.T, B)
 
 
 # ---------------------------------------------------------------- dgrad
 def _dgrad_kernel(tile_ref, rt_ref, first_ref, lanes_ref,
-                  dy_ref, b_ref, a_ref, o_ref):
+                  dy_ref, b_ref, at_ref, o_ref):
     f = pl.program_id(1)
 
     @pl.when(first_ref[f] == 1)
     def _zero():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    dy = dy_ref[...]
-    # dxa = dy · B[rt]^T : contract d_out
-    dxa = jax.lax.dot_general(dy, b_ref[...], (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    lane = jax.lax.broadcasted_iota(jnp.int32, dxa.shape, 1)
-    dxa = jnp.where(lane < lanes_ref[f], dxa, 0.0).astype(dy_ref.dtype)
-    # dx += dxa · A[:, rt]^T : contract r_blk
-    o_ref[...] += jax.lax.dot_general(dxa, a_ref[...],
-                                      (((1,), (1,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
+    dxa = _nt(dy_ref[...], b_ref[...])                 # dy · B[rt]ᵀ
+    dxa = _rank_mask(dxa, lanes_ref[f], 1).astype(dy_ref.dtype)
+    o_ref[...] += jnp.dot(dxa, at_ref[...],            # dxa · A[:, rt]ᵀ
+                          preferred_element_type=jnp.float32)
 
 
 def ragged_lora_dgrad(dy_s: jax.Array, A: jax.Array, B: jax.Array,
                       meta: RaggedMeta, *, block_t: int = 128,
-                      block_i: int = 512,
-                      interpret: bool = True) -> jax.Array:
+                      block_i: int = 512) -> jax.Array:
     """dx = ((dy_s · B^T) masked) · A^T over active rank tiles only —
     one fused launch where the masked path needs two grouped-mm
     launches plus a full-width HBM intermediate.  dy_s: (T, d_out)
@@ -234,158 +242,121 @@ def ragged_lora_dgrad(dy_s: jax.Array, A: jax.Array, B: jax.Array,
                          lambda j, f, tm, rt, fi, ln: (tm[f], 0)),
             pl.BlockSpec((meta.r_blk, d_out),
                          lambda j, f, tm, rt, fi, ln: (rt[f], 0)),
-            pl.BlockSpec((block_i, meta.r_blk),
-                         lambda j, f, tm, rt, fi, ln: (j, rt[f])),
+            pl.BlockSpec((meta.r_blk, block_i),
+                         lambda j, f, tm, rt, fi, ln: (rt[f], j)),
         ],
         out_specs=pl.BlockSpec((block_t, block_i),
                                lambda j, f, tm, rt, fi, ln: (tm[f], j)),
     )
-    return pl.pallas_call(
+    return pallas_call(
         _dgrad_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, d_in), jnp.float32),
-        interpret=interpret,
-    )(*_prefetch((tile, rtile, first, lanes)), dy_s, B, A)
+    )(*_prefetch((tile, rtile, first, lanes)), dy_s, B, A.T)
 
 
-# ------------------------------------------------------- packed mm (xa)
-def _xa_kernel(tile_ref, rt_ref, first_ref, lanes_ref, x_ref, a_ref,
-               o_ref):
+# ----------------------------------------- packed rank-major mm (xaᵀ, dxaᵀ)
+def _rank_major_kernel(tile_ref, rt_ref, first_ref, lanes_ref, v_ref,
+                       w_ref, o_ref):
     f = pl.program_id(0)
-    xa = jnp.dot(x_ref[...], a_ref[...],
-                 preferred_element_type=jnp.float32)
-    lane = jax.lax.broadcasted_iota(jnp.int32, xa.shape, 1)
-    o_ref[...] = jnp.where(lane < lanes_ref[f], xa,
-                           0.0).astype(o_ref.dtype)
+    out = _nt(w_ref[...], v_ref[...])                  # (r_blk, block_t)
+    o_ref[...] = _rank_mask(out, lanes_ref[f], 0).astype(o_ref.dtype)
+
+
+def _rank_major(v: jax.Array, Wt: jax.Array, meta: RaggedMeta,
+                block_t: int) -> jax.Array:
+    """(R, T) with out[seg_k, t] = Wt[seg_k] · v_t for k = adapter(t),
+    rank-masked; other segments' entries are never visited (and never
+    read).  Wt: (R, d) rank-major weight slab, v: (T, d)."""
+    T, d = v.shape
+    assert T == len(meta.tile_jobs) * block_t, (T, block_t,
+                                                len(meta.tile_jobs))
+    tile, rtile, first, lanes = meta.fwd_flat
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(len(tile),),
+        in_specs=[
+            pl.BlockSpec((block_t, d),
+                         lambda f, tm, rt, fi, ln: (tm[f], 0)),
+            pl.BlockSpec((meta.r_blk, d),
+                         lambda f, tm, rt, fi, ln: (rt[f], 0)),
+        ],
+        out_specs=pl.BlockSpec((meta.r_blk, block_t),
+                               lambda f, tm, rt, fi, ln: (rt[f], tm[f])),
+    )
+    return pallas_call(
+        _rank_major_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((meta.total_r, T), v.dtype),
+    )(*_prefetch((tile, rtile, first, lanes)), v, Wt)
 
 
 def ragged_xa(x: jax.Array, A: jax.Array, meta: RaggedMeta, *,
-              block_t: int = 128, interpret: bool = True) -> jax.Array:
-    """Packed compact intermediate xa: (T, R) with xa[t, seg_k] =
-    x_t · A[:, seg_k] for k = adapter(t), rank-masked; other segments'
-    columns are never visited (and never read).  Wgrad operand."""
-    T, d_in = x.shape
-    assert T == len(meta.tile_jobs) * block_t, (T, block_t,
-                                                len(meta.tile_jobs))
-    R = meta.total_r
-    tile, rtile, first, lanes = meta.fwd_flat
-    grid = (len(tile),)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_t, d_in),
-                         lambda f, tm, rt, fi, ln: (tm[f], 0)),
-            pl.BlockSpec((d_in, meta.r_blk),
-                         lambda f, tm, rt, fi, ln: (0, rt[f])),
-        ],
-        out_specs=pl.BlockSpec((block_t, meta.r_blk),
-                               lambda f, tm, rt, fi, ln: (tm[f], rt[f])),
-    )
-    return pl.pallas_call(
-        _xa_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, R), x.dtype),
-        interpret=interpret,
-    )(*_prefetch((tile, rtile, first, lanes)), x, A)
-
-
-def _dxa_kernel(tile_ref, rt_ref, first_ref, lanes_ref, dy_ref, b_ref,
-                o_ref):
-    f = pl.program_id(0)
-    dxa = jax.lax.dot_general(dy_ref[...], b_ref[...],
-                              (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    lane = jax.lax.broadcasted_iota(jnp.int32, dxa.shape, 1)
-    o_ref[...] = jnp.where(lane < lanes_ref[f], dxa,
-                           0.0).astype(o_ref.dtype)
+              block_t: int = 128) -> jax.Array:
+    """Packed compact intermediate, rank-major: xaᵀ (R, T) with
+    xaᵀ[seg_k, t] = (x_t · A[:, seg_k])ᵀ for k = adapter(t),
+    rank-masked.  Wgrad operand (dB)."""
+    return _rank_major(x, A.T, meta, block_t)
 
 
 def ragged_dxa(dy_s: jax.Array, B: jax.Array, meta: RaggedMeta, *,
-               block_t: int = 128, interpret: bool = True) -> jax.Array:
-    """Packed masked cotangent of xa: (T, R) with dxa[t, seg_k] =
-    dy_s_t · B[seg_k]^T, rank-masked.  Wgrad operand (dA)."""
-    T, d_out = dy_s.shape
-    assert T == len(meta.tile_jobs) * block_t, (T, block_t,
-                                                len(meta.tile_jobs))
-    R = meta.total_r
-    tile, rtile, first, lanes = meta.fwd_flat
-    grid = (len(tile),)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_t, d_out),
-                         lambda f, tm, rt, fi, ln: (tm[f], 0)),
-            pl.BlockSpec((meta.r_blk, d_out),
-                         lambda f, tm, rt, fi, ln: (rt[f], 0)),
-        ],
-        out_specs=pl.BlockSpec((block_t, meta.r_blk),
-                               lambda f, tm, rt, fi, ln: (tm[f], rt[f])),
-    )
-    return pl.pallas_call(
-        _dxa_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, R), dy_s.dtype),
-        interpret=interpret,
-    )(*_prefetch((tile, rtile, first, lanes)), dy_s, B)
+               block_t: int = 128) -> jax.Array:
+    """Packed masked cotangent of xa, rank-major: dxaᵀ (R, T) with
+    dxaᵀ[seg_k, t] = B[seg_k] · dy_s_t, rank-masked.  Wgrad operand
+    (dA)."""
+    return _rank_major(dy_s, B, meta, block_t)
 
 
 # ---------------------------------------------------------------- wgrad
-def _wgrad_kernel(tile_ref, rt_ref, first_ref, u_ref, v_ref, o_ref):
+def _wgrad_kernel(tile_ref, rt_ref, first_ref, ut_ref, v_ref, o_ref):
     f = pl.program_id(1)
 
     @pl.when(first_ref[f] == 1)
     def _zero():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    # (block_t, r_blk)^T · (block_t, block_o) -> (r_blk, block_o)
-    o_ref[...] += jax.lax.dot_general(u_ref[...], v_ref[...],
-                                      (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
+    # (r_blk, block_t) · (block_t, block_o) -> (r_blk, block_o)
+    o_ref[...] += jnp.dot(ut_ref[...], v_ref[...],
+                          preferred_element_type=jnp.float32)
 
 
-def ragged_wgrad(u: jax.Array, v: jax.Array, meta: RaggedMeta, *,
-                 block_t: int = 128, block_o: int = 512,
-                 interpret: bool = True) -> jax.Array:
+def ragged_wgrad(ut: jax.Array, v: jax.Array, meta: RaggedMeta, *,
+                 block_t: int = 128, block_o: int = 512) -> jax.Array:
     """Segment-aware ragged wgrad: out[seg_k] = Σ_{t: adapter(t)=k}
-    u[t, seg_k]^T · v_t.
+    ut[seg_k, t] · v_t.
 
-    u: (T, R) packed (xa or dxa), v: (T, d) dense.  Returns (R, d) f32 —
-    dB directly (u=xa, v=dy_s), or dA TRANSPOSED (u=dxa, v=x; caller
-    transposes to (d_in, R)).  Flat grid in (adapter, rank tile, token
-    tile) order: each output block's token-tile visits are consecutive,
-    and only true-rank tiles of adapters that own tokens launch."""
-    T, R = u.shape
+    ut: (R, T) packed rank-major (xaᵀ or dxaᵀ), v: (T, d) dense.
+    Returns (R, d) f32 — dB directly (ut=xaᵀ, v=dy_s), or dA TRANSPOSED
+    (ut=dxaᵀ, v=x; caller transposes to (d_in, R)).  Flat grid in
+    (adapter, rank tile, token tile) order: each output block's
+    token-tile visits are consecutive, and only true-rank tiles of
+    adapters that own tokens launch."""
+    R, T = ut.shape
     d = v.shape[-1]
     assert R == meta.total_r and T == len(meta.tile_jobs) * block_t, \
         (T, R, block_t, len(meta.tile_jobs))
     block_o = _fit_block(d, block_o)
     tile, rtile, first = meta.wgrad_flat
-    grid = (d // block_o, max(len(tile), 1))
     if len(tile) == 0:       # degenerate: no tokens at all
         return jnp.zeros((R, d), jnp.float32)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=grid,
+        grid=(d // block_o, len(tile)),
         in_specs=[
-            pl.BlockSpec((block_t, meta.r_blk),
-                         lambda j, f, tm, rt, fi: (tm[f], rt[f])),
+            pl.BlockSpec((meta.r_blk, block_t),
+                         lambda j, f, tm, rt, fi: (rt[f], tm[f])),
             pl.BlockSpec((block_t, block_o),
                          lambda j, f, tm, rt, fi: (tm[f], j)),
         ],
         out_specs=pl.BlockSpec((meta.r_blk, block_o),
                                lambda j, f, tm, rt, fi: (rt[f], j)),
     )
-    out = pl.pallas_call(
+    out = pallas_call(
         _wgrad_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, d), jnp.float32),
-        interpret=interpret,
-    )(*_prefetch((tile, rtile, first)), u, v)
+    )(*_prefetch((tile, rtile, first)), ut, v)
     # adapters with zero token tiles are never visited — their output
     # rows are uninitialized memory; the true gradient is zero.
     vis = meta.visited_rows

@@ -3,6 +3,11 @@ submesh partitioner of the cluster controller (DESIGN.md §9).
 
 FUNCTIONS, not module-level constants — importing this module must not
 touch jax device state (the dry-run sets XLA_FLAGS before first init).
+
+Every mesh here has ``Auto`` axes: the step builders run full-manual or
+partial-manual ``jax.shard_map`` over them and leave the remaining axes
+to GSPMD placement (sharding/rules.py), which is what ``Auto`` means.
+(``jax.make_mesh`` alone now defaults to ``Explicit`` axes.)
 """
 from __future__ import annotations
 
@@ -10,13 +15,20 @@ import math
 from typing import List, Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape: Sequence[int], axes: Sequence[str], devices=None):
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 (512 chips, 2 pods)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def device_shares(weights: Sequence[float], n_devices: int) -> List[int]:
@@ -105,8 +117,7 @@ def partition_mesh(sizes: Sequence[int], devices: Optional[Sequence] = None,
         _check_stages(stages, int(s), "group slice")
     out, cur = [], 0
     for s in sizes:
-        out.append(jax.make_mesh((int(s),), (axis,),
-                                 devices=devices[cur:cur + s]))
+        out.append(_mesh((int(s),), (axis,), devices=devices[cur:cur + s]))
         cur += s
     return out
 
@@ -124,8 +135,7 @@ def stage_mesh(mesh, stages: int, axis: str = "data",
     devs = list(mesh.devices.flat)
     n = len(devs)
     stages = _check_stages(stages, n, "group submesh")
-    return jax.make_mesh((stages, n // stages), (stage_axis, axis),
-                         devices=devs)
+    return _mesh((stages, n // stages), (stage_axis, axis), devices=devs)
 
 
 def make_local_mesh(model: int = 1, stages: int = 1):
@@ -151,6 +161,5 @@ def make_local_mesh(model: int = 1, stages: int = 1):
     while d % stages:
         stages -= 1
     if stages == 1:
-        return jax.make_mesh((d, model), ("data", "model"))
-    return jax.make_mesh((stages, d // stages, model),
-                         ("stage", "data", "model"))
+        return _mesh((d, model), ("data", "model"))
+    return _mesh((stages, d // stages, model), ("stage", "data", "model"))

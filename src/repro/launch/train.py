@@ -14,6 +14,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
 from repro.core.jobs import LoRAJobSpec
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def cmd_train(args):
@@ -87,9 +88,10 @@ def main():
     t.add_argument("--batch-size", type=int, default=2)
     t.add_argument("--seq-len", type=int, default=64)
     t.add_argument("--lr", type=float, default=1e-3)
-    t.add_argument("--impl", default="ref",
+    # --impl / --block-t unset: the platform's (pallas/128 on TPU)
+    t.add_argument("--impl", default=None,
                    choices=("ref", "pallas", "xla", "loop"))
-    t.add_argument("--block-t", type=int, default=8)
+    t.add_argument("--block-t", type=int, default=None)
     t.add_argument("--no-aimd", action="store_true")
     t.add_argument("--reduced", action="store_true")
     t.set_defaults(fn=cmd_train)
@@ -98,8 +100,9 @@ def main():
     s.add_argument("--arch", default="tinyllama-1.1b", choices=ARCH_IDS)
     s.add_argument("--requests", type=int, default=8)
     s.add_argument("--tokens", type=int, default=8)
-    s.add_argument("--impl", default="ref")
-    s.add_argument("--block-t", type=int, default=8)
+    s.add_argument("--impl", default=None,
+                   choices=("ref", "pallas", "xla", "loop"))
+    s.add_argument("--block-t", type=int, default=None)
     s.add_argument("--reduced", action="store_true")
     s.set_defaults(fn=cmd_serve)
 
@@ -111,6 +114,7 @@ def main():
     c.set_defaults(fn=cmd_simulate)
 
     args = ap.parse_args()
+    enable_compile_cache()
     args.fn(args)
 
 

@@ -417,11 +417,16 @@ def forward(cfg: ModelConfig, params: dict, adapters: Optional[dict],
             lora: Optional[MultiLoRA], batch: dict, *,
             caches: Optional[list] = None, cache_pos=None,
             ring: bool = False, remat: bool = False,
-            unroll_layers: bool = False):
+            unroll_layers: bool = False,
+            logits_at: Optional[jax.Array] = None):
     """Full model. batch keys: tokens / frames / patches (+tokens).
 
     Returns (logits, aux_loss, new_caches, text_offset).
     logits: (B, S, vocab) — for VLM, S covers patches+text (slice by offset).
+    ``logits_at`` (B,) keeps one position per row, taken BEFORE the
+    vocabulary projection: logits are then (B, 1, vocab).  Serving
+    prefill reads only each request's last prompt position, and the
+    full (B, S, vocab) tensor would not fit a chip at serving batch.
     """
     # per-row cache_pos (B,) — batched serving decode where every
     # right-padded request sits at its own depth — only reaches the
@@ -457,6 +462,8 @@ def forward(cfg: ModelConfig, params: dict, adapters: Optional[dict],
         aux = aux + a
         if new_caches is not None:
             new_caches.append(nc)
+    if logits_at is not None:
+        x = x[jnp.arange(B), logits_at][:, None]
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     return _logits(cfg, params, x), aux, new_caches, text_off
 
@@ -524,15 +531,17 @@ def loss_fn(cfg: ModelConfig, params: dict, adapters: dict,
 
 def decode_step(cfg: ModelConfig, params: dict, adapters: Optional[dict],
                 lora: Optional[MultiLoRA], token: jax.Array, pos,
-                caches: list, *, ring: bool = False):
+                caches: list, *, ring: bool = False,
+                logits_at: Optional[jax.Array] = None):
     """One decode step. token: (B, 1..S) int32; pos: scalar position or a
     per-row ``(B,)`` vector (fused serving: each request at its own depth).
 
-    Returns (logits (B, S, V), new_caches).
+    Returns (logits (B, S, V), new_caches) — (B, 1, V) with
+    ``logits_at`` (see ``forward``).
     """
     logits, _, new_caches, _ = forward(
         cfg, params, adapters, lora, {"tokens": token},
-        caches=caches, cache_pos=pos, ring=ring)
+        caches=caches, cache_pos=pos, ring=ring, logits_at=logits_at)
     return logits, new_caches
 
 

@@ -156,8 +156,8 @@ _DEQUANT_IMPL = "xla"
 def set_dequant_impl(impl: str) -> None:
     """Select the dequant-matmul kernel process-wide ("xla" | "pallas").
 
-    Like ops.set_interpret, call BEFORE building train steps — the impl
-    is baked into traced programs. Numerics are identical either way."""
+    Call BEFORE building train steps — the impl is baked into traced
+    programs. Numerics are identical either way."""
     global _DEQUANT_IMPL
     if impl not in ("xla", "pallas"):
         raise ValueError(f"unknown dequant impl {impl!r}")

@@ -43,6 +43,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.lora import MultiLoRA
+from repro.kernels.ops import kernel_defaults
 from repro.models import model as M
 from repro.serve.pool import AdapterPool, FusedAdapters
 
@@ -74,8 +75,10 @@ class ServeEngine:
     cfg: ModelConfig
     params: dict
     pool: AdapterPool
-    impl: str = "xla"                 # fused-LoRA kernel impl
-    block_t: int = 8                  # token tile (128 on real TPU)
+    # fused-LoRA kernel impl and token tile; None = the platform's
+    # (ops.kernel_defaults: pallas/128 on TPU, xla/8 elsewhere)
+    impl: Optional[str] = None
+    block_t: Optional[int] = None
     greedy: bool = True
     # int8 frozen backbone for serving (models/quant): halves the
     # resident weight bytes AND the per-token weight streaming — decode
@@ -87,6 +90,8 @@ class ServeEngine:
 
     def __post_init__(self):
         cfg = self.cfg
+        self.impl, self.block_t = kernel_defaults(self.impl, self.block_t,
+                                                  cpu_impl="xla")
         if self.quantize is not None:
             from repro.models import quant
             self.params = quant.quantize_params(self.params, self.quantize)
@@ -187,9 +192,9 @@ class ServeEngine:
             # prefill: same decode_step at width S, static pos 0 (right
             # padding makes column index == absolute position)
             logits, caches = M.decode_step(cfg, params, adapters, lora,
-                                           tokens, 0, caches)
-            first = jnp.argmax(logits[jnp.arange(B), lens - 1],
-                               axis=-1).astype(jnp.int32)
+                                           tokens, 0, caches,
+                                           logits_at=lens - 1)
+            first = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
 
             def body(carry, _):
                 caches, tok, pos = carry

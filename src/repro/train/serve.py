@@ -21,7 +21,7 @@ align to ``block_t``).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, List, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 import jax
@@ -59,8 +59,8 @@ def pad_requests(reqs: Sequence[Request], pad_to: int) -> Dict[str, np.ndarray]:
 
 
 def serve_batch(cfg: ModelConfig, jobs: Sequence[LoRAJobSpec],
-                reqs: Sequence[Request], *, impl: str = "ref",
-                block_t: int = 8, params=None, adapters=None,
+                reqs: Sequence[Request], *, impl: Optional[str] = None,
+                block_t: Optional[int] = None, params=None, adapters=None,
                 seed: int = 0, greedy: bool = True) -> List[np.ndarray]:
     """Prefill + decode a batch of adapter-tagged requests.
 
@@ -70,6 +70,7 @@ def serve_batch(cfg: ModelConfig, jobs: Sequence[LoRAJobSpec],
     tokens that were never really sampled for them).
     """
     ssm = SharedSuperModel(cfg, list(jobs), impl=impl, block_t=block_t)
+    impl, block_t = ssm.impl, ssm.block_t
     if params is None or adapters is None:
         params, adapters = ssm.init(jax.random.PRNGKey(seed))
 

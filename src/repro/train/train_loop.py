@@ -24,7 +24,7 @@ __all__ = ["train_group", "TrainReport", "GroupRuntime"]
 
 def train_group(cfg: ModelConfig, jobs: Sequence[LoRAJobSpec], *,
                 steps: int = 20, lr: float = 1e-3, seed: int = 0,
-                impl: str = "ref", block_t: int = 8,
+                impl: Optional[str] = None, block_t: Optional[int] = None,
                 adaptive_nano: bool = True, nano_batches: int = 1,
                 remat: bool = True, quantize: Optional[str] = None,
                 chunk_size: int = 4,

@@ -345,7 +345,6 @@ def migration_across_meshes():
 def gather_solo_bitexact():
     """scatter-to-solo-position + psum reassembly is bit-preserving."""
     from functools import partial
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.kernels.ops import gather_solo
 
@@ -356,8 +355,9 @@ def gather_solo_bitexact():
     def body(t, pos):
         return gather_solo(t, "data", pos, 16)
 
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
-                          out_specs=P(), check_rep=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh,
+                              in_specs=(P("data"), P("data")),
+                              out_specs=P(), check_vma=False))
     out = f(x, jnp.asarray(perm))
     want = np.zeros_like(np.asarray(x))
     want[perm] = np.asarray(x)

@@ -183,20 +183,26 @@ def test_donation_does_not_consume_caller_state(tiny_cfg, two_jobs):
         assert np.array_equal(np.asarray(got), want)
 
 
-def test_interpret_override(monkeypatch):
-    """set_interpret / REPRO_INTERPRET control the Pallas interpret flag
-    without a source edit (real-TPU runs set REPRO_INTERPRET=0)."""
-    assert ops.get_interpret() is True           # default on CPU CI
-    try:
-        ops.set_interpret(False)
-        assert ops.get_interpret() is False
-    finally:
-        ops.set_interpret(True)
-    assert ops.get_interpret() is True
-    monkeypatch.setenv("REPRO_INTERPRET", "0")
-    assert ops._env_interpret() is False
-    monkeypatch.setenv("REPRO_INTERPRET", "1")
-    assert ops._env_interpret() is True
+def test_pallas_mode_follows_backend():
+    """Pallas is interpreted where the program is lowered for CPU and
+    compiled (a Mosaic ``tpu_custom_call``) where it is lowered for TPU
+    — decided at lowering, with no switch to set.  Cross-platform
+    export lowers both from this CPU host without a chip."""
+    from jax import export
+    from repro.kernels.fused_lora import dequant_matmul_pallas
+    x = jnp.ones((128, 256), jnp.bfloat16)
+    q = jnp.ones((256, 512), jnp.int8)
+    s = jnp.ones((512,), jnp.float32)
+    fn = jax.jit(dequant_matmul_pallas)
+    mods = {p: export.export(fn, platforms=[p])(x, q, s).mlir_module()
+            for p in ("cpu", "tpu")}
+    assert "tpu_custom_call" not in mods["cpu"]
+    assert "tpu_custom_call" in mods["tpu"]
+    # and the interpreted CPU program computes the kernel's result
+    want = (np.asarray(x, np.float32) @ np.asarray(q, np.float32)
+            * np.asarray(s))
+    np.testing.assert_array_equal(np.asarray(fn(x, q, s), np.float32),
+                                  want)
 
 
 def test_valid_nano_counts_divisor_enumeration():

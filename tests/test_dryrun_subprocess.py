@@ -15,7 +15,6 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np
     import jax, jax.numpy as jnp
-    from jax.sharding import Mesh
 
     from repro.configs import get_config
     from repro.configs.base import InputShape
@@ -23,10 +22,12 @@ SCRIPT = textwrap.dedent("""
     from repro.core.ssm import SharedSuperModel
     from repro.data.pipeline import FusedBatcher
     from repro.optim import adamw
+    from repro.launch.mesh import make_local_mesh
     from repro.optim.schedule import constant
     from repro.sharding import rules, use_mesh
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_local_mesh(model=2)          # (4, 2), Auto axes for GSPMD
+    assert dict(mesh.shape) == {"data": 4, "model": 2}
     cfg = get_config("tinyllama-1.1b").reduced()
     jobs = [LoRAJobSpec("a", rank=4, batch_size=2, seq_len=32),
             LoRAJobSpec("b", rank=8, batch_size=2, seq_len=32)]

@@ -103,7 +103,8 @@ def test_ragged_matches_masked_reference(impl, ranks, rows, eq):
 def test_ragged_pallas_kernels_in_isolation():
     """The four ragged pallas launches against their dense oracles —
     incl. an empty adapter whose never-visited wgrad rows must come
-    back exactly zero."""
+    back exactly zero.  The packed intermediates come back rank-major,
+    (R, T)."""
     from repro.kernels import ragged as rg
     rng = np.random.default_rng(5)
     seq, bt = 8, 8
@@ -122,9 +123,10 @@ def test_ragged_pallas_kernels_in_isolation():
                                rtol=1e-5, atol=1e-5)
 
     # xa / dxa packed intermediates (active segments only)
-    xa = np.asarray(rg.ragged_xa(x, Ap, meta, block_t=bt))
+    xat = rg.ragged_xa(x, Ap, meta, block_t=bt)
+    xa = np.asarray(xat).T
     dy = jnp.asarray(rng.standard_normal(got.shape).astype(np.float32))
-    dxa = np.asarray(rg.ragged_dxa(dy, Bp, meta, block_t=bt))
+    dxa = np.asarray(rg.ragged_dxa(dy, Bp, meta, block_t=bt)).T
     for k in range(4):
         off, rp = layout.slice_of(k)
         rows_k = np.asarray(ids) == k
@@ -146,8 +148,7 @@ def test_ragged_pallas_kernels_in_isolation():
                                    atol=1e-4)
 
     # ragged wgrad: dB = Σ_seg xa^T dy, empty adapter rows exactly zero
-    dB = np.asarray(rg.ragged_wgrad(jnp.asarray(xa), dy, meta,
-                                    block_t=bt))
+    dB = np.asarray(rg.ragged_wgrad(xat, dy, meta, block_t=bt))
     off3, rp3 = layout.slice_of(3)
     assert not dB[off3:off3 + rp3].any()       # job 3 owns no tokens
     for k in range(3):
