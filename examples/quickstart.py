@@ -31,7 +31,8 @@ def main():
               f"{rep.per_job_losses[0][k]:.3f} -> "
               f"{rep.per_job_losses[-1][k]:.3f}")
     print(f"AIMD nano-batch trajectory: {rep.nano_history}")
-    print(f"~{rep.steps_per_sec:.2f} fused steps/sec ({rep.samples_per_sec:.1f} samples/sec) on this host")
+    print(f"~{rep.steps_per_sec:.2f} fused steps/sec "
+          f"({rep.tokens_per_sec:.1f} real tokens/sec) on this host")
 
 
 if __name__ == "__main__":

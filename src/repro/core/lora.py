@@ -270,8 +270,11 @@ class MultiLoRA:
             return None
         return tuple(r // f for r in self.rows_all)
 
+    @jax.named_scope("lora")
     def apply(self, x: jax.Array, ab: Dict[str, jax.Array]) -> jax.Array:
-        """x: (B, S, d_in) -> (B, S, d_out) LoRA delta (scaled)."""
+        """x: (B, S, d_in) -> (B, S, d_out) LoRA delta (scaled), under the
+        named scope ``lora`` with every kernel launch and the XLA ops
+        around it."""
         from repro.kernels import ops  # late import: kernels are optional
         A, B = ab["A"], ab["B"]
         bsz, seq, d_in = x.shape

@@ -469,7 +469,6 @@ class SharedSuperModel:
         from jax.sharding import PartitionSpec as P
         from repro.data.pipeline import shard_permutation
         from repro.launch.mesh import stage_mesh
-        from repro.models.layers import rms_norm
 
         cfg, K = self.cfg, self.num_jobs
         P_st = int(stages)
@@ -591,26 +590,14 @@ class SharedSuperModel:
                         cfg, plan[i], params["segments"][i], ad_segs[i],
                         lora_full, x, positions, None, None, False, remat)
                     aux_tail = aux_tail + a
-                x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-                logits = M._logits(cfg, params, x)
-                labels = nb["labels"]
-                if text_off:
-                    logits = logits[:, text_off:]
-                if cfg.causal:
-                    logits = logits[:, :-1]
-                    labels = labels[:, 1:]
-                mask = nb.get("loss_mask")
-                if mask is not None:
-                    mask = mask[:, -labels.shape[-1]:]
-                from repro.models.layers import cross_entropy
-                tok_loss = cross_entropy(logits, labels, mask=mask)
-                seq_loss = tok_loss.sum(axis=-1)
-                onehot = jax.nn.one_hot(nb["adapter_ids"], K,
-                                        dtype=jnp.float32)
-                per_job = (onehot.T @ seq_loss) / denom
-                # LOCAL partial, where-masked to the owning stage — no
-                # psum inside the differentiated loss
-                total = (jnp.where(last, per_job.sum() + aux_tail, 0.0)
+                # LOCAL partial (a context with no data axis), where-
+                # masked to the owning stage — no psum inside the
+                # differentiated loss
+                tail, out = M._head_loss(
+                    cfg, params, self.lora_ctx(nb["adapter_ids"]), nb, x,
+                    aux_tail, text_off, denom)
+                per_job = out["per_job"]
+                total = (jnp.where(last, tail, 0.0)
                          + jnp.where(first, aux_pre, 0.0) + aux_scan)
                 aux_out = jnp.where(last, aux_tail, 0.0) \
                     + jnp.where(first, aux_pre, 0.0) + aux_scan
@@ -788,7 +775,9 @@ def _stage_psum_unscanned(grads: dict, si: int, axis: str) -> dict:
 
 def _per_job_token_counts(batch: dict, K: int, causal: bool,
                           clip: bool = True) -> jax.Array:
-    """Full-batch per-job loss-token counts (denominators).
+    """Full-batch per-job loss-token counts (denominators), summed
+    elementwise rather than by a one-hot matmul: every matmul of the
+    step then belongs to a named layer of the model.
 
     ``clip=False`` returns the raw counts — REQUIRED for per-shard
     partials that are psum'ed into a global denominator: clipping must
@@ -804,7 +793,7 @@ def _per_job_token_counts(batch: dict, K: int, causal: bool,
         m = mask[:, 1:] if causal else mask
         counts = m.astype(jnp.float32).sum(-1)
     onehot = jax.nn.one_hot(ids, K, dtype=jnp.float32)
-    raw = onehot.T @ counts
+    raw = (onehot * counts[:, None]).sum(0)
     return jnp.clip(raw, 1) if clip else raw
 
 

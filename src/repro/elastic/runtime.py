@@ -16,7 +16,7 @@ import copy
 import os
 import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 import jax
@@ -36,8 +36,10 @@ from repro.optim.schedule import constant
 
 @dataclass
 class TrainReport:
+    """What the group trained.  Tokens count when a chunk is collected,
+    for the batches that chunk trained on (a prefetched chunk counts at
+    its own collection); compiles count at each step-cache miss."""
     steps: int = 0
-    samples_per_step: int = 0             # true samples (tile padding excl.)
     losses: List[float] = field(default_factory=list)
     per_job_losses: List[np.ndarray] = field(default_factory=list)
     step_times: List[float] = field(default_factory=list)
@@ -47,6 +49,10 @@ class TrainReport:
     # step's executed-schedule occupancy counters) surface here
     # without widening the report schema per mode
     last_metrics: Optional[Dict[str, np.ndarray]] = None
+    real_tokens: int = 0          # loss-mask ones
+    row_tokens: int = 0           # rows x seq_len, tile-padding rows incl.
+    compiles: int = 0             # step-cache misses
+    compile_s: float = 0.0        # host seconds compiling them
 
     @property
     def steps_per_sec(self) -> float:
@@ -54,13 +60,10 @@ class TrainReport:
             np.mean(self.step_times[1:] or self.step_times))
 
     @property
-    def samples_per_sec(self) -> float:
-        # each step consumes one fused batch of samples_per_step sequences
-        return self.steps_per_sec * max(self.samples_per_step, 1)
-
-    @property
-    def last_step_time(self) -> float:
-        return self.step_times[-1] if self.step_times else 0.0
+    def tokens_per_sec(self) -> float:
+        """Real (loss-mask) tokens trained per second of chunk time."""
+        t = float(np.sum(self.step_times))
+        return self.real_tokens / t if t > 0 else 0.0
 
     def measured_step_time(self, window: int = 8) -> float:
         """Robust recent step time: min over the last *window* steps
@@ -87,6 +90,9 @@ class PendingChunk:
     # prefetch advances the batcher) — what the checkpoint hook must
     # persist so a restore resumes on exactly the next unseen tokens
     stream_states: Optional[List[str]] = None
+    index: int = 0                       # the chunk's number in spans
+    # (real, row) tokens of the chunk's batches, counted at collection
+    tokens: Tuple[int, int] = (0, 0)
 
 
 class GroupRuntime:
@@ -306,8 +312,9 @@ class GroupRuntime:
         self._staged: Optional[dict] = None
         self._staged_len = 0
         self._staged_rewind: List[str] = []
-        self.report = TrainReport(
-            samples_per_step=sum(s.batch_size for s in self.specs))
+        self._staged_tokens = (0, 0)
+        self._chunks_dispatched = 0
+        self.report = TrainReport()
 
     # ------------------------------------------------------- constructors
     @classmethod
@@ -383,45 +390,59 @@ class GroupRuntime:
         group's very first chunk."""
         key = (n, chunk)
         if key not in self._step_cache:
-            fn = self.ssm.make_train_step(lr_fn=self.lr_fn, nano_batches=n,
-                                          remat=self.remat,
-                                          weight_decay=self.weight_decay,
-                                          steps=chunk,
-                                          unroll=self.scan_unroll,
-                                          mesh=self.mesh,
-                                          data_axis=self.data_axis,
-                                          grad_sync=self.grad_sync,
-                                          tp_mode=self.tp_mode,
-                                          pipeline_stages=self.pipeline_stages,
-                                          nano_order=self.nano_order)
-            jitted = jax.jit(fn, donate_argnums=(1, 2))
-            if self.mesh is None or self.tp_mode != "auto":
-                # full-manual shard_map (dp and pipeline): no GSPMD
-                # axes to constrain
-                self._step_cache[key] = jitted.lower(*args).compile()
-            else:
-                # trace with the mesh active so the backbone's logical
-                # sharding constraints resolve onto its auto axes (TP /
-                # sequence parallelism over "model"); the manual data
-                # axis is excluded — inside shard_map it is local.
-                from repro.sharding import use_mesh
-                with use_mesh(self.mesh, manual=(self.data_axis,)):
-                    self._step_cache[key] = jitted.lower(*args).compile()
+            with jax.profiler.TraceAnnotation("tlora.compile",
+                                              chunk=self._chunks_dispatched):
+                t0 = time.perf_counter()
+                self._step_cache[key] = self._compile_step(n, chunk, args)
+            self.report.compiles += 1
+            self.report.compile_s += time.perf_counter() - t0
         return self._step_cache[key]
 
-    def _stage(self, n: int):
-        """Stage the next *n* fused batches on device (leading chunk axis).
+    def _compile_step(self, n: int, chunk: int, args):
+        fn = self.ssm.make_train_step(lr_fn=self.lr_fn, nano_batches=n,
+                                      remat=self.remat,
+                                      weight_decay=self.weight_decay,
+                                      steps=chunk,
+                                      unroll=self.scan_unroll,
+                                      mesh=self.mesh,
+                                      data_axis=self.data_axis,
+                                      grad_sync=self.grad_sync,
+                                      tp_mode=self.tp_mode,
+                                      pipeline_stages=self.pipeline_stages,
+                                      nano_order=self.nano_order)
+        jitted = jax.jit(fn, donate_argnums=(1, 2))
+        if self.mesh is None or self.tp_mode != "auto":
+            # full-manual shard_map (dp and pipeline): no GSPMD
+            # axes to constrain
+            return jitted.lower(*args).compile()
+        # trace with the mesh active so the backbone's logical
+        # sharding constraints resolve onto its auto axes (TP /
+        # sequence parallelism over "model"); the manual data
+        # axis is excluded — inside shard_map it is local.
+        from repro.sharding import use_mesh
+        with use_mesh(self.mesh, manual=(self.data_axis,)):
+            return jitted.lower(*args).compile()
+
+    def _stage(self, n: int, chunk: int):
+        """Stage the next *n* fused batches on device (leading chunk axis),
+        for the chunk numbered *chunk*; returns them with their (real,
+        row) token counts.
 
         Sharded mode permutes rows into the shard-major layout (each
         shard: every job's next rows/D rows, job-major — see
         data/pipeline.shard_permutation) and places each leaf with rows
         over the data axis, so the host->device transfer is already the
         final layout (no device-side reshard)."""
-        batches = self.batcher.next_batches(n)
-        if self.mesh is None:
-            return {k: jnp.asarray(v) for k, v in batches.items()}
-        return {k: jax.device_put(v[:, self._perm], self._batch_sharding)
-                for k, v in batches.items()}
+        with jax.profiler.TraceAnnotation("tlora.stage", chunk=chunk):
+            batches = self.batcher.next_batches(n)
+            if self.mesh is None:
+                staged = {k: jnp.asarray(v) for k, v in batches.items()}
+            else:
+                staged = {k: jax.device_put(v[:, self._perm],
+                                            self._batch_sharding)
+                          for k, v in batches.items()}
+        return staged, (int(np.count_nonzero(batches["loss_mask"])),
+                        int(batches["tokens"].size))
 
     def dispatch_chunk(self, length: Optional[int] = None, *,
                        prefetch: int = 0,
@@ -444,39 +465,48 @@ class GroupRuntime:
         """
         L = int(length or self.chunk_size)
         assert L >= 1
-        if self._staged is not None:
-            # a mismatched prefetch would orphan stream data the batcher
-            # already consumed (breaking the lossless data contract), so
-            # it is a caller bug — fail loudly instead of dropping it
-            assert self._staged_len == L, (self._staged_len, L)
-            staged, self._staged = self._staged, None
-        else:
-            staged = self._stage(L)
-        step_fn = self._get_step(
-            self.n, L, (self.params, self.adapters, self.opt_state, staged))
-        t0 = time.perf_counter()
-        # async dispatch: nothing below blocks until the metrics fetch
-        self.adapters, self.opt_state, metrics = step_fn(
-            self.params, self.adapters, self.opt_state, staged)
-        # snapshot stream positions BEFORE prefetching: the checkpoint
-        # hook fires at collect time, after the prefetch has advanced
-        # the live streams past data this chunk never trained on —
-        # persisting the live position would make a restore skip the
-        # prefetched batches and silently fork the trajectory
-        streams = None
-        if self.checkpoint_every:
-            from repro.checkpoint.checkpoint import stream_state
-            streams = [stream_state(s) for s in self.batcher.streams]
-        if prefetch > 0:                     # overlaps with device compute
-            from repro.checkpoint.checkpoint import stream_state
-            self._staged_rewind = [stream_state(s)
-                                   for s in self.batcher.streams]
-            self._staged = self._stage(prefetch)
-            self._staged_len = prefetch
+        i = self._chunks_dispatched
+        with jax.profiler.TraceAnnotation("tlora.dispatch", chunk=i):
+            if self._staged is not None:
+                # a mismatched prefetch would orphan stream data the
+                # batcher already consumed (breaking the lossless data
+                # contract), so it is a caller bug — fail loudly instead
+                # of dropping it
+                assert self._staged_len == L, (self._staged_len, L)
+                staged, self._staged = self._staged, None
+                tokens = self._staged_tokens
+            else:
+                staged, tokens = self._stage(L, i)
+            step_fn = self._get_step(
+                self.n, L, (self.params, self.adapters, self.opt_state,
+                            staged))
+            t0 = time.perf_counter()
+            # async dispatch: nothing below blocks until the metrics fetch
+            with jax.profiler.TraceAnnotation("tlora.launch", chunk=i):
+                self.adapters, self.opt_state, metrics = step_fn(
+                    self.params, self.adapters, self.opt_state, staged)
+            # snapshot stream positions BEFORE prefetching: the
+            # checkpoint hook fires at collect time, after the prefetch
+            # has advanced the live streams past data this chunk never
+            # trained on — persisting the live position would make a
+            # restore skip the prefetched batches and silently fork the
+            # trajectory
+            streams = None
+            if self.checkpoint_every:
+                from repro.checkpoint.checkpoint import stream_state
+                streams = [stream_state(s) for s in self.batcher.streams]
+            if prefetch > 0:                 # overlaps with device compute
+                from repro.checkpoint.checkpoint import stream_state
+                self._staged_rewind = [stream_state(s)
+                                       for s in self.batcher.streams]
+                self._staged, self._staged_tokens = self._stage(prefetch,
+                                                                i + 1)
+                self._staged_len = prefetch
+            self._chunks_dispatched += 1
         return PendingChunk(metrics=metrics, length=L, t0=t0,
                             count_aimd=L > 1 if count_aimd is None
                             else count_aimd,
-                            stream_states=streams)
+                            stream_states=streams, index=i, tokens=tokens)
 
     def collect_chunk(self, pending: PendingChunk,
                       log: Optional[Callable[[str], None]] = None
@@ -485,11 +515,22 @@ class GroupRuntime:
 
         One host sync per chunk; also advances per-job step accounting,
         feeds AIMD, and fires the periodic checkpoint hook."""
-        log = log or (lambda s: None)
+        i = pending.index
+        with jax.profiler.TraceAnnotation("tlora.collect", chunk=i):
+            with jax.profiler.TraceAnnotation("tlora.wait", chunk=i):
+                # the chunk's one host sync
+                host = jax.device_get(pending.metrics)
+            dt = (time.perf_counter() - pending.t0) / pending.length
+            with jax.profiler.TraceAnnotation("tlora.fold", chunk=i):
+                return self._fold(pending, host, dt, log or (lambda s: None))
+
+    def _fold(self, pending: PendingChunk, host, dt: float,
+              log: Callable[[str], None]) -> TrainReport:
+        """Fold a fetched chunk into the report, AIMD and the hooks."""
         rep = self.report
         L = pending.length
-        host = jax.device_get(pending.metrics)  # the chunk's one host sync
-        dt = (time.perf_counter() - pending.t0) / L
+        rep.real_tokens += pending.tokens[0]
+        rep.row_tokens += pending.tokens[1]
         losses = np.atleast_1d(np.asarray(host["loss"], np.float64))
         per_job = np.atleast_2d(np.asarray(host["per_job_loss"]))
         rep.last_metrics = {k: np.asarray(v) for k, v in host.items()}
@@ -591,7 +632,7 @@ class GroupRuntime:
             if (self.n, L) in self._step_cache:
                 continue
             marks = [stream_state(s) for s in self.batcher.streams]
-            staged = self._stage(L)
+            staged, _ = self._stage(L, self._chunks_dispatched)
             for s, mark in zip(self.batcher.streams, marks):
                 restore_stream_state(s, mark)
             self._get_step(self.n, L, (self.params, self.adapters,

@@ -109,6 +109,7 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     return pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=(BH, n_q, n_kv),
         in_specs=[
             pl.BlockSpec((1, block_q, hd), lambda b, i, j: (b, i, 0)),

@@ -106,6 +106,7 @@ def fused_lora_pallas(x: jax.Array, A: jax.Array, B: jax.Array,
     )
     return pallas_call(
         _fused_lora_kernel,
+        name="fused_lora_fwd",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, d_out), x.dtype),
     )(tile_map, ranks, x, A, B)
@@ -169,6 +170,7 @@ def grouped_wgrad_pallas(x: jax.Array, g: jax.Array, tile_map: jax.Array,
     )
     out = pallas_call(
         _grouped_wgrad_kernel,
+        name="grouped_lora_wgrad",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((K, d_in, d_out), jnp.float32),
     )(tile_map, x, g)
@@ -213,6 +215,7 @@ def dequant_matmul_pallas(x: jax.Array, w_q: jax.Array, scale: jax.Array,
     s2 = scale.reshape(1, d_out).astype(jnp.float32)
     return pallas_call(
         _dequant_mm_kernel,
+        name="dequant_matmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_t, d_in), lambda i, j: (i, 0)),
@@ -253,6 +256,7 @@ def grouped_matmul_pallas(x: jax.Array, W: jax.Array, tile_map: jax.Array,
     )
     return pallas_call(
         _grouped_mm_kernel,
+        name="grouped_lora_matmul",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, d_out), x.dtype),
     )(tile_map, x, W)

@@ -199,6 +199,7 @@ def ragged_lora_fwd(x: jax.Array, A: jax.Array, B: jax.Array,
     )
     return pallas_call(
         _fwd_kernel,
+        name="ragged_lora_fwd",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, d_out), jnp.float32),
     )(*_prefetch((tile, rtile, first, lanes)), x, A.T, B)
@@ -250,6 +251,7 @@ def ragged_lora_dgrad(dy_s: jax.Array, A: jax.Array, B: jax.Array,
     )
     return pallas_call(
         _dgrad_kernel,
+        name="ragged_lora_dgrad",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, d_in), jnp.float32),
     )(*_prefetch((tile, rtile, first, lanes)), dy_s, B, A.T)
@@ -286,6 +288,7 @@ def _rank_major(v: jax.Array, Wt: jax.Array, meta: RaggedMeta,
     )
     return pallas_call(
         _rank_major_kernel,
+        name="ragged_lora_rank_major",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((meta.total_r, T), v.dtype),
     )(*_prefetch((tile, rtile, first, lanes)), v, Wt)
@@ -354,6 +357,7 @@ def ragged_wgrad(ut: jax.Array, v: jax.Array, meta: RaggedMeta, *,
     )
     out = pallas_call(
         _wgrad_kernel,
+        name="ragged_lora_wgrad",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, d), jnp.float32),
     )(*_prefetch((tile, rtile, first)), ut, v)

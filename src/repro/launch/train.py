@@ -34,7 +34,11 @@ def cmd_train(args):
     rep = out["report"]
     print(f"\nfinal loss {rep.losses[-1]:.4f}  "
           f"avg step {np.mean(rep.step_times[1:]):.3f}s  "
+          f"{rep.tokens_per_sec:.1f} real tokens/s  "
           f"nano trajectory {rep.nano_history}")
+    print(f"{rep.compiles} step compiles in {rep.compile_s:.1f}s  "
+          f"padding {1 - rep.real_tokens / max(rep.row_tokens, 1):.1%} "
+          f"of {rep.row_tokens} row tokens")
 
 
 def cmd_serve(args):

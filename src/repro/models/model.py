@@ -23,6 +23,7 @@ Modality frontends (audio conv codec, ViT) are stubs per the assignment:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import zlib
 from dataclasses import dataclass
@@ -276,39 +277,55 @@ def init_caches(cfg: ModelConfig, batch: int, buf: int, ring: bool) -> list:
 
 
 # ----------------------------------------------------------------- blocks
-def apply_block(cfg: ModelConfig, spec: LayerSpec, p: dict, ad: dict,
-                lora: Optional[MultiLoRA], x: jax.Array, positions,
-                cache, cache_pos, ring: bool):
-    """One pre-norm block. Returns (x, new_cache, aux_loss)."""
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    aux = jnp.zeros((), jnp.float32)
+_ATTN_MIXERS = ("attn", "local_attn", "mla")
+
+
+def _mixer(cfg, spec: LayerSpec, p: dict, ad: dict, lora, h, positions,
+           cache, cache_pos, ring: bool):
     if spec.mixer in ("attn", "local_attn"):
-        out, new_cache = attn_block(
+        return attn_block(
             cfg, p["attn"], h, positions=positions, lora=lora, lora_ab=ad,
             cache=cache, cache_pos=cache_pos,
             local=(spec.mixer == "local_attn"),
             ring=ring or (spec.mixer == "local_attn" and cache is not None))
-    elif spec.mixer == "mla":
-        out, new_cache = mla_block(cfg, p["attn"], h, positions=positions,
-                                   lora=lora, lora_ab=ad, cache=cache,
-                                   cache_pos=cache_pos, ring=ring)
-    elif spec.mixer == "ssd":
-        out, new_cache = ssd_block(cfg, p["ssd"], h, lora=lora, lora_ab=ad,
-                                   cache=cache)
-    elif spec.mixer == "rglru":
-        out, new_cache = rglru_block(cfg, p["rg"], h, lora=lora, lora_ab=ad,
-                                     cache=cache)
-    else:
-        raise ValueError(spec.mixer)
+    if spec.mixer == "mla":
+        return mla_block(cfg, p["attn"], h, positions=positions,
+                         lora=lora, lora_ab=ad, cache=cache,
+                         cache_pos=cache_pos, ring=ring)
+    if spec.mixer == "ssd":
+        return ssd_block(cfg, p["ssd"], h, lora=lora, lora_ab=ad,
+                         cache=cache)
+    if spec.mixer == "rglru":
+        return rglru_block(cfg, p["rg"], h, lora=lora, lora_ab=ad,
+                           cache=cache)
+    raise ValueError(spec.mixer)
+
+
+def apply_block(cfg: ModelConfig, spec: LayerSpec, p: dict, ad: dict,
+                lora: Optional[MultiLoRA], x: jax.Array, positions,
+                cache, cache_pos, ring: bool):
+    """One pre-norm block. Returns (x, new_cache, aux_loss).
+
+    The attention mixers run under the named scope ``attention`` and
+    the FFN under ``mlp``, each with its norm: the compiled ops carry the
+    scope in their metadata, so a profile splits the step by layer."""
+    aux = jnp.zeros((), jnp.float32)
+    with (jax.named_scope("attention") if spec.mixer in _ATTN_MIXERS
+          else contextlib.nullcontext()):
+        h = rms_norm(x, p["ln1"], cfg.norm_eps)
+        out, new_cache = _mixer(cfg, spec, p, ad, lora, h, positions,
+                                cache, cache_pos, ring)
     x = x + out
     if spec.ffn != "none":
-        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-        if spec.ffn == "moe":
-            out2, aux = moe_ffn(cfg, p["ffn"], h2)
-        else:
-            out2 = swiglu(p["ffn"], h2)
+        with jax.named_scope("mlp"):
+            h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+            if spec.ffn == "moe":
+                out2, aux = moe_ffn(cfg, p["ffn"], h2)
+            else:
+                out2 = swiglu(p["ffn"], h2)
         x = x + out2
     return x, new_cache, aux
+
 
 
 def _apply_segment(cfg, seg: Segment, p: dict, ad: dict,
@@ -393,17 +410,18 @@ def embed_inputs(cfg: ModelConfig, params: dict, batch: dict,
 
     Returns (x, text_offset) where logits/labels align from text_offset on.
     """
-    dt = dtype_of(cfg)
-    if cfg.family == "audio":
-        x = batch["frames"].astype(dt) @ params["frontend"]
-        S = x.shape[1]
-        x = x + _sinusoid(S, cfg.d_model, pos_offset).astype(dt)[None]
-        return x, 0
-    if cfg.family == "vlm" and "patches" in batch:
-        pe = batch["patches"].astype(dt) @ params["frontend"]
-        te = params["embed"][batch["tokens"]]
-        return jnp.concatenate([pe, te], axis=1), pe.shape[1]
-    return params["embed"][batch["tokens"]], 0
+    with jax.named_scope("embed"):
+        dt = dtype_of(cfg)
+        if cfg.family == "audio":
+            x = batch["frames"].astype(dt) @ params["frontend"]
+            S = x.shape[1]
+            x = x + _sinusoid(S, cfg.d_model, pos_offset).astype(dt)[None]
+            return x, 0
+        if cfg.family == "vlm" and "patches" in batch:
+            pe = batch["patches"].astype(dt) @ params["frontend"]
+            te = params["embed"][batch["tokens"]]
+            return jnp.concatenate([pe, te], axis=1), pe.shape[1]
+        return params["embed"][batch["tokens"]], 0
 
 
 def _logits(cfg, params, x):
@@ -428,6 +446,23 @@ def forward(cfg: ModelConfig, params: dict, adapters: Optional[dict],
     prefill reads only each request's last prompt position, and the
     full (B, S, vocab) tensor would not fit a chip at serving batch.
     """
+    x, aux, new_caches, text_off = _backbone(
+        cfg, params, adapters, lora, batch, caches=caches,
+        cache_pos=cache_pos, ring=ring, remat=remat,
+        unroll_layers=unroll_layers)
+    if logits_at is not None:
+        x = x[jnp.arange(x.shape[0]), logits_at][:, None]
+    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+    return _logits(cfg, params, x), aux, new_caches, text_off
+
+
+def _backbone(cfg: ModelConfig, params: dict, adapters: Optional[dict],
+              lora: Optional[MultiLoRA], batch: dict, *,
+              caches: Optional[list] = None, cache_pos=None,
+              ring: bool = False, remat: bool = False,
+              unroll_layers: bool = False):
+    """Embedding and every block: (hidden states before the final norm,
+    aux_loss, new_caches, text_offset)."""
     # per-row cache_pos (B,) — batched serving decode where every
     # right-padded request sits at its own depth — only reaches the
     # token frontends (the audio sinusoid stub needs a shared offset)
@@ -462,10 +497,7 @@ def forward(cfg: ModelConfig, params: dict, adapters: Optional[dict],
         aux = aux + a
         if new_caches is not None:
             new_caches.append(nc)
-    if logits_at is not None:
-        x = x[jnp.arange(B), logits_at][:, None]
-    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-    return _logits(cfg, params, x), aux, new_caches, text_off
+    return x, aux, new_caches, text_off
 
 
 def loss_fn(cfg: ModelConfig, params: dict, adapters: dict,
@@ -478,10 +510,23 @@ def loss_fn(cfg: ModelConfig, params: dict, adapters: dict,
 
     Each job's loss is normalized over *its own* token count, so gradients
     w.r.t. job j's adapter are identical to training j alone (up to the
-    backbone being frozen — which it is).  Total = sum_j loss_j.
+    backbone being frozen — which it is).  Total = sum_j loss_j.  The
+    final norm, the vocabulary projection and the loss run under the
+    named scope ``head_loss``.
     """
-    logits, aux, _, off = forward(cfg, params, adapters, lora, batch,
-                                  remat=remat, unroll_layers=unroll_layers)
+    x, aux, _, off = _backbone(cfg, params, adapters, lora, batch,
+                               remat=remat, unroll_layers=unroll_layers)
+    return _head_loss(cfg, params, lora, batch, x, aux, off, per_job_denom)
+
+
+@jax.named_scope("head_loss")
+def _head_loss(cfg: ModelConfig, params: dict, lora: Optional[MultiLoRA],
+               batch: dict, x, aux, off, per_job_denom):
+    """``loss_fn``'s tail from the final hidden states *x*: the final
+    norm, the vocabulary projection and the per-job loss.  The pipeline
+    step's last stage calls it too."""
+    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+    logits = _logits(cfg, params, x)
     labels = batch["labels"]
     if off:
         logits = logits[:, off:]

@@ -71,6 +71,7 @@ def _col_broadcast(vec: jax.Array, col_jobs, a_leaf: bool) -> jax.Array:
     return cols if a_leaf else cols[:, None]
 
 
+@jax.named_scope("optimizer")
 def update(grads, state: AdamWState, params, *, lr, b1: float = 0.9,
            b2: float = 0.999, eps: float = 1e-8,
            weight_decay: float = 0.0,

@@ -143,3 +143,75 @@ def test_full_width_chunked_pallas_step_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def _pallas_launches():
+    """(launch, kernel function, name, is a LoRA kernel, fn, shapes)."""
+    from repro.kernels import flash_attention as fa
+    layout = RankLayout(RANKS, multiple=16)
+    meta_s = rg.RaggedMeta.build([k for k in range(4) for _ in range(2)],
+                                 layout)
+    R, T_s, d, K, rp = meta_s.total_r, 4 * 2 * BT, 256, len(RANKS), 16
+    kw = dict(meta=meta_s, block_t=BT)
+    return [
+        ("ragged fwd", rg._fwd_kernel, "ragged_lora_fwd", True,
+         functools.partial(rg.ragged_lora_fwd, **kw),
+         [((T_s, d), BF), ((d, R), BF), ((R, d), BF)]),
+        ("ragged dgrad", rg._dgrad_kernel, "ragged_lora_dgrad", True,
+         functools.partial(rg.ragged_lora_dgrad, **kw),
+         [((T_s, d), BF), ((d, R), BF), ((R, d), BF)]),
+        ("ragged xa", rg._rank_major_kernel, "ragged_lora_rank_major", True,
+         functools.partial(rg.ragged_xa, **kw), [((T_s, d), BF),
+                                                 ((d, R), BF)]),
+        ("ragged wgrad", rg._wgrad_kernel, "ragged_lora_wgrad", True,
+         functools.partial(rg.ragged_wgrad, **kw), [((R, T_s), BF),
+                                                    ((T_s, d), BF)]),
+        ("masked fwd", pk._fused_lora_kernel, "fused_lora_fwd", True,
+         functools.partial(pk.fused_lora_pallas, block_t=BT),
+         [((T_s, d), BF), ((K, d, rp), BF), ((K, rp, d), BF),
+          ((T_s // BT,), jnp.int32), ((K,), jnp.int32)]),
+        ("masked wgrad", pk._grouped_wgrad_kernel, "grouped_lora_wgrad",
+         True, lambda x, g, tm: pk.grouped_wgrad_pallas(x, g, tm, K,
+                                                        block_t=BT),
+         [((T_s, d), BF), ((T_s, rp), BF), ((T_s // BT,), jnp.int32)]),
+        ("masked dx", pk._grouped_mm_kernel, "grouped_lora_matmul", True,
+         functools.partial(pk.grouped_matmul_pallas, block_t=BT),
+         [((T_s, rp), BF), ((K, rp, d), BF), ((T_s // BT,), jnp.int32)]),
+        ("dequant", pk._dequant_mm_kernel, "dequant_matmul", False,
+         functools.partial(pk.dequant_matmul_pallas, block_t=BT),
+         [((T_s, d), BF), ((d, d), jnp.int8), ((d,), jnp.float32)]),
+        ("flash", fa._flash_fwd_kernel, "flash_attention_fwd", False,
+         lambda q, k, v: fa.flash_attention_fwd(q, k, v, causal=True),
+         [((2, 256, 128), BF)] * 3),
+    ]
+
+
+@pytest.mark.parametrize("launch", range(9), ids=lambda i: [
+    "ragged_fwd", "ragged_dgrad", "ragged_xa", "ragged_wgrad", "masked_fwd",
+    "masked_wgrad", "masked_dx", "dequant", "flash"][i])
+def test_pallas_launch_names(one_chip, launch):
+    """Every Pallas launch carries its kernel's name: the Mosaic call's
+    instruction is named by it, and the benchmark's ``kernels.is_lora``
+    classifies the name as it classifies the kernel function's symbol.
+    Every LoRA kernel's name says ``lora``; the dequant and flash
+    kernels' do not."""
+    import re
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import kernels
+    import xtrace
+    _, kernel_fn, name, lora, fn, shapes = _pallas_launches()[launch]
+    text = _compile(fn, one_chip, *shapes).as_text()
+    calls = re.findall(r"%(\S+) = [^\n]*custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    assert calls and all(re.fullmatch(rf"{name}(\.\d+)?", c)
+                         for c in calls), calls
+    assert ("lora" in name) is lora
+    assert kernels.is_lora(name) is kernels.is_lora(kernel_fn.__name__) \
+        is lora
+    # the benchmark's decoder finds a name for every launch (which one
+    # depends on the Python call sites recorded in the Mosaic body)
+    assert set(xtrace.kernel_names(text)) == set(calls)
