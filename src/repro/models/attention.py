@@ -8,7 +8,13 @@ One blockwise implementation serves every mode:
                        recomputes scores per KV chunk from (q,k,v,out,lse)
                        instead of storing them, and every dot runs with
                        bf16 inputs + f32 accumulation (§Perf iterations
-                       1-2 in EXPERIMENTS.md).
+                       1-2 in EXPERIMENTS.md).  Where the program lowers
+                       for TPU and the geometry is static (no window, no
+                       offset, whole lane-aligned blocks, no GSPMD axis
+                       to partition over), that VJP's forward and
+                       backward are the Pallas kernels of
+                       kernels/flash_attention.py; elsewhere they are
+                       the XLA chunk scans below.
   * decode           — q (S=1..n) over a cache buffer (full or ring).
 """
 from __future__ import annotations
@@ -20,14 +26,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.lora import MultiLoRA, proj
+from repro.kernels import flash_attention as flash_kernel
 from repro.models.layers import apply_rope, dense_init, rms_norm, rms_norm_init
-from repro.sharding import shard
+from repro.sharding import partitioned, shard
 
 NEG_BIG = -1e30
 _F32_ATTN = False    # legacy f32-attention path (EXPERIMENTS.md §Perf A/B)
 _USE_FLASH = True    # flash custom-VJP for training (§Perf iteration 2)
-_PALLAS_FLASH = False  # route fwd through the Pallas kernel (TPU target;
-#                        interpret-mode on CPU — enable for kernel runs)
 
 
 # ----------------------------------------------------------------- flash
@@ -44,27 +49,18 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     window:   if set, keys with qpos - kpos >= window are masked out.
 
     Static geometry (training/prefill) routes through the flash custom
-    VJP; traced offsets (decode) use the plain scan (never differentiated).
+    VJP, on the Pallas kernels where the geometry allows (see
+    ``_make_flash``); traced offsets (decode) use the plain scan (never
+    differentiated).
     """
-    if (_PALLAS_FLASH and window is None and q_offset == 0
-            and kv_len == q.shape[1] == k.shape[1]):
-        # Pallas kernel path (forward; bwd still uses the XLA flash VJP)
-        from repro.kernels.flash_attention import flash_attention_fwd
-        B, Sq, H, hd = q.shape
-        KV = k.shape[2]
-        G = H // KV
-        qf = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, hd)
-        kf = jnp.repeat(k, G, axis=2).transpose(0, 2, 1, 3) \
-            .reshape(B * H, -1, hd)
-        vf = jnp.repeat(v, G, axis=2).transpose(0, 2, 1, 3) \
-            .reshape(B * H, -1, v.shape[-1])
-        out = flash_attention_fwd(qf, kf, vf, causal=causal,
-                                  block_q=min(chunk, 128),
-                                  block_k=min(chunk, 128))
-        return out.reshape(B, H, Sq, -1).transpose(0, 2, 1, 3)
     if (_USE_FLASH and not _F32_ATTN
             and isinstance(q_offset, int) and isinstance(kv_len, int)):
-        fn = _make_flash(q_offset, kv_len, causal, window, chunk)
+        kernel = (q_offset == 0 and window is None
+                  and kv_len == q.shape[1] == k.shape[1]
+                  and not partitioned()
+                  and flash_kernel.supports(q.shape, k.shape, v.shape,
+                                            q.dtype.itemsize))
+        fn = _make_flash(q_offset, kv_len, causal, window, chunk, kernel)
         return fn(q, k, v)
     out, _ = _chunked_attention_fwd(q, k, v, q_offset=q_offset,
                                     kv_len=kv_len, causal=causal,
@@ -180,30 +176,26 @@ def _chunked_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
 # --------------------------------------------------------- flash custom VJP
 @functools.lru_cache(maxsize=256)
 def _make_flash(q_offset: int, kv_len: int, causal: bool,
-                window: Optional[int], chunk: int):
+                window: Optional[int], chunk: int, kernel: bool = False):
     """Flash attention with hand-written backward (static geometry).
 
     Forward = the online-softmax chunk scan above (saves out + lse, never
     the (Sq x Skv) score matrix).  Backward re-walks the KV chunks,
     recomputing p = exp(s - lse) per chunk; every dot takes bf16 inputs
     with f32 accumulation.
+
+    ``kernel``: the geometry is one the Pallas kernels take (the caller
+    checks).  Forward and backward are then chosen at lowering: the
+    kernels where the program is compiled for TPU, these XLA scans
+    everywhere else.  Both save the same residuals.
     """
 
-    @jax.custom_vjp
-    def f(q, k, v):
-        out, _ = _chunked_attention_fwd(q, k, v, q_offset=q_offset,
-                                        kv_len=kv_len, causal=causal,
-                                        window=window, chunk=chunk)
-        return out
+    def xla_fwd(q, k, v):
+        return _chunked_attention_fwd(q, k, v, q_offset=q_offset,
+                                      kv_len=kv_len, causal=causal,
+                                      window=window, chunk=chunk)
 
-    def fwd(q, k, v):
-        out, lse = _chunked_attention_fwd(q, k, v, q_offset=q_offset,
-                                          kv_len=kv_len, causal=causal,
-                                          window=window, chunk=chunk)
-        return out, (q, k, v, out, lse)
-
-    def bwd(res, dout):
-        q, k, v, out, lse = res
+    def xla_bwd(q, k, v, out, lse, dout):
         B, Sq, H, hd = q.shape
         Skv, KV = k.shape[1], k.shape[2]
         vd = v.shape[-1]
@@ -264,6 +256,26 @@ def _make_flash(q_offset: int, kv_len: int, causal: bool,
         if pad:
             dk, dv = dk[:, :Skv], dv[:, :Skv]
         return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype))
+
+    fwd_impl, bwd_impl = xla_fwd, xla_bwd
+    if kernel:
+        fwd_impl = lambda *a: jax.lax.platform_dependent(
+            *a, tpu=functools.partial(flash_kernel.flash_fwd, causal=causal),
+            default=xla_fwd)
+        bwd_impl = lambda *a: jax.lax.platform_dependent(
+            *a, tpu=functools.partial(flash_kernel.flash_bwd, causal=causal),
+            default=xla_bwd)
+
+    @jax.custom_vjp
+    def f(q, k, v):
+        return fwd_impl(q, k, v)[0]
+
+    def fwd(q, k, v):
+        out, lse = fwd_impl(q, k, v)
+        return out, (q, k, v, out, lse)
+
+    def bwd(res, dout):
+        return bwd_impl(*res, dout)
 
     f.defvjp(fwd, bwd)
     return f

@@ -69,6 +69,17 @@ def use_mesh(mesh: Optional[Mesh], seq_over_batch: bool = False,
         _state.manual = prev_manual
 
 
+def partitioned() -> bool:
+    """Whether code traced now runs under GSPMD over more than one
+    device: an active mesh with a non-manual axis wider than 1.  A
+    Pallas kernel there would need a partitioning rule; inside a
+    full-manual ``shard_map`` (or with no mesh) it sees local arrays."""
+    mesh = _current()
+    return mesh is not None and any(
+        int(mesh.shape[a]) > 1 for a in mesh.axis_names
+        if a not in _manual_axes())
+
+
 def logical_to_mesh(mesh: Mesh, name: LogicalAxis) -> Tuple[str, ...]:
     if name is None:
         return ()

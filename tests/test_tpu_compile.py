@@ -5,7 +5,9 @@ tests compile — without running — the kernels at tinyllama-1.1b widths
 (d_model 2048, d_ff 5632, GQA k/v 256) for a v5e that is described, not
 attached: the five ragged launches for mixed ranks {64,16,8,4} (packed
 R = 112 at 16-wide rank tiles), the masked forward with its grouped
-wgrad, the dequant matmul, and one whole chunked Pallas train step.
+wgrad, the dequant matmul, the flash-attention forward and backward at
+the benchmark cells' attention shapes, and one whole chunked Pallas
+train step.
 
 The topology is described inside a fixture, never at import: only the
 worker that runs this file loads the TPU compiler.  Compiles happen in
@@ -13,6 +15,7 @@ the test's own process.
 """
 import functools
 import os
+import re
 
 import pytest
 
@@ -140,9 +143,35 @@ def test_full_width_chunked_pallas_step_compiles(one_chip):
     compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
         on_chip(params), on_chip(adapters), on_chip(opt),
         on_chip(batches)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the attention core runs on the flash kernels (head dim 64, padded
+    # to one lane tile), forward, recomputed forward and backward
+    for name in ("flash_attention_fwd", "flash_attention_dq",
+                 "flash_attention_dkv"):
+        assert re.search(rf"%{name}(\.\d+)? = ", text), name
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("B,S,H,KV", [(32, 512, 48, 8), (8, 1024, 64, 8)],
+                         ids=["internlm2-20b", "qwen1.5-110b"])
+def test_flash_kernels_compile_at_cell_shapes(one_chip, B, S, H, KV):
+    """The flash forward and both backward launches at the benchmark
+    cells' attention shapes (rows x seq, query/KV heads, hd 128)."""
+    from repro.kernels import flash_attention as fa
+    hd = 128
+
+    def fwd_bwd(q, k, v, dout):
+        out, lse = fa.flash_fwd(q, k, v, causal=True)
+        return fa.flash_bwd(q, k, v, out, lse, dout, causal=True)
+
+    q, kv = ((B, S, H, hd), BF), ((B, S, KV, hd), BF)
+    text = _compile(fwd_bwd, one_chip, q, kv, kv, q).as_text()
+    calls = re.findall(r"%(\S+) = [^\n]*custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    assert sorted(c.split(".")[0] for c in calls) == [
+        "flash_attention_dkv", "flash_attention_dq", "flash_attention_fwd"]
 
 
 def _pallas_launches():
@@ -152,6 +181,9 @@ def _pallas_launches():
     meta_s = rg.RaggedMeta.build([k for k in range(4) for _ in range(2)],
                                  layout)
     R, T_s, d, K, rp = meta_s.total_r, 4 * 2 * BT, 256, len(RANKS), 16
+    q, kv = ((2, 256, 2, 128), BF), ((2, 256, 1, 128), BF)
+    qkv = [q, kv, kv]
+    residuals = qkv + [q, ((2, 2, 256), jnp.float32), q]   # out, lse, dout
     kw = dict(meta=meta_s, block_t=BT)
     return [
         ("ragged fwd", rg._fwd_kernel, "ragged_lora_fwd", True,
@@ -181,20 +213,25 @@ def _pallas_launches():
          functools.partial(pk.dequant_matmul_pallas, block_t=BT),
          [((T_s, d), BF), ((d, d), jnp.int8), ((d,), jnp.float32)]),
         ("flash", fa._flash_fwd_kernel, "flash_attention_fwd", False,
-         lambda q, k, v: fa.flash_attention_fwd(q, k, v, causal=True),
-         [((2, 256, 128), BF)] * 3),
+         lambda q, k, v: fa.flash_fwd(q, k, v, causal=True)[0], qkv),
+        ("flash dq", fa._flash_dq_kernel, "flash_attention_dq", False,
+         lambda *res: fa.flash_bwd(*res, causal=True)[0], residuals),
+        ("flash dkv", fa._flash_dkv_kernel, "flash_attention_dkv", False,
+         lambda *res: fa.flash_bwd(*res, causal=True)[1:], residuals),
     ]
 
 
-@pytest.mark.parametrize("launch", range(9), ids=lambda i: [
+@pytest.mark.parametrize("launch", range(11), ids=lambda i: [
     "ragged_fwd", "ragged_dgrad", "ragged_xa", "ragged_wgrad", "masked_fwd",
-    "masked_wgrad", "masked_dx", "dequant", "flash"][i])
+    "masked_wgrad", "masked_dx", "dequant", "flash", "flash_dq",
+    "flash_dkv"][i])
 def test_pallas_launch_names(one_chip, launch):
     """Every Pallas launch carries its kernel's name: the Mosaic call's
     instruction is named by it, and the benchmark's ``kernels.is_lora``
     classifies the name as it classifies the kernel function's symbol.
     Every LoRA kernel's name says ``lora``; the dequant and flash
-    kernels' do not."""
+    kernels' do not.  The dq and dk/dv launches are compiled each
+    alone: the other backward launch is dead code there."""
     import re
     import sys
     bench = os.path.join(os.path.dirname(os.path.dirname(
