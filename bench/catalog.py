@@ -3,7 +3,9 @@
 ``BENCHMARK.json`` names each cell's configuration and traffic mix; each
 lives in a file of its own, found by name:
 
-    bench/configs/<config>.json    model sizes, as run, beside the source's
+    bench/configs/<config>.json    model sizes, as run, beside the source's,
+                                   and the model family
+    bench/families/<family>.py     the family's weights, FLOPs, reference
     bench/traffic/<traffic>.json   jobs, document lengths, chips, sources
     bench/limits/<cell>.json       the limits of the correctness check
     bench/metrics/<metric>.py      one reader per per-layer metric
@@ -13,26 +15,13 @@ A cell is added by adding files: nothing here names a cell.
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import importlib.util
 import json
 import os
 from typing import Any, Callable, Dict, List, Optional
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
-
-# source config.json key -> ModelConfig field, for the dense GQA family
-_HF_KEYS = {
-    "num_hidden_layers": "num_layers",
-    "hidden_size": "d_model",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps",
-    "tie_word_embeddings": "tie_embeddings",
-    "torch_dtype": "dtype",
-}
 
 
 @dataclasses.dataclass
@@ -48,6 +37,10 @@ class Cell:
     @property
     def model(self) -> Dict[str, Any]:
         return self.config["model"]
+
+    @property
+    def family(self):
+        return family(self.config)
 
 
 def _read_json(path: str) -> Any:
@@ -85,18 +78,30 @@ def load_cell(root: str, name: str, bench_dir: Optional[str] = None) -> Cell:
                            if _applies(m, name)])
 
 
+def families() -> List[str]:
+    """The model families the benchmark knows: ``families/<name>.py``."""
+    return sorted(f[:-3] for f in os.listdir(os.path.join(BENCH_DIR,
+                                                          "families"))
+                  if f.endswith(".py") and not f.startswith("_"))
+
+
+def family(config: Dict[str, Any]):
+    """The family module a configuration file names by ``"family"``:
+    its weights, adapted projections, FLOPs and reference."""
+    name = config.get("family")
+    if name not in families():
+        raise ValueError(f"configuration {config.get('name')!r} names "
+                         f"family {name!r}; known families: {families()}")
+    return importlib.import_module("families." + name)
+
+
 def model_config(cell: Cell):
     """The program's ``ModelConfig`` for the cell: the registry entry the
     configuration file names, with every size the file states."""
     from repro.configs import get_config
-    m = cell.model
-    kw = {field: m[key] for key, field in _HF_KEYS.items() if key in m}
-    kw["head_dim"] = m.get("head_dim", m["hidden_size"]
-                           // m["num_attention_heads"])
-    kw["attn_bias"] = bool(m["qkv_bias"])
+    kw = cell.family.model_fields(cell.model)
     # text-only training of the language model: no modality frontend
-    kw.update(family="dense", frontend_dim=0, num_patches=0,
-              name=cell.config["name"])
+    kw.update(frontend_dim=0, num_patches=0, name=cell.config["name"])
     return dataclasses.replace(get_config(cell.config["registry"]), **kw)
 
 

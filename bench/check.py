@@ -43,18 +43,28 @@ ROW_BUCKET = 128
 
 def job_leaves(tree: Dict, layout, ranks) -> List[Dict[Tuple, np.ndarray]]:
     """Per job, ``{(layer, target, "A"|"B"): un-padded slice}`` from the
-    program's packed adapter-shaped tree (host numpy)."""
-    seg = tree["segments"][0]["0"]
-    out = []
-    for k, r in enumerate(ranks):
-        off, _ = layout.slice_of(k)
-        d = {}
-        for t, ab in seg.items():
-            A, B = np.asarray(ab["A"]), np.asarray(ab["B"])
-            for l in range(A.shape[0]):
-                d[(l, t, "A")] = A[l, :, off:off + r]
-                d[(l, t, "B")] = B[l, off:off + r, :]
-        out.append(d)
+    program's packed adapter-shaped tree (host numpy).  Every segment of
+    the tree is read, in order, each layer by its global index: a
+    segment of n layers a cycle holds layer ``base + c * n + j`` at
+    cycle c, slot j (``models/model.segment_plan``), its leaves stacked
+    over the cycles where it is scanned."""
+    out: List[Dict[Tuple, np.ndarray]] = [{} for _ in ranks]
+    base = 0
+    for seg in tree["segments"]:
+        n, cycles = len(seg), 0
+        for j in range(n):
+            for t, ab in seg[str(j)].items():
+                A, B = np.asarray(ab["A"]), np.asarray(ab["B"])
+                if A.ndim == 2:                     # an unscanned layer
+                    A, B = A[None], B[None]
+                cycles = A.shape[0]
+                for c in range(cycles):
+                    for k, r in enumerate(ranks):
+                        off, _ = layout.slice_of(k)
+                        layer = base + c * n + j
+                        out[k][(layer, t, "A")] = A[c, :, off:off + r]
+                        out[k][(layer, t, "B")] = B[c, off:off + r, :]
+        base += n * cycles
     return out
 
 
@@ -76,14 +86,20 @@ def leaf_gap(prog: List[Dict], ref: List[Dict], keep,
 
 class Reference:
     """The reference's run of the cell's first steps, from the seed
-    (``fp8``: the control, in float8 matmuls)."""
+    (``fp8``: the control, in float8 matmuls).  The configuration's
+    family names the reference module."""
 
     def __init__(self, cell, seed: int, steps: int, lr: float,
                  fp8: bool = False):
+        import importlib
+
         import jax
         import jax.numpy as jnp
-        from reference.model import adamw, row_loss_and_grad
+        from reference.adamw import adamw
 
+        fam = cell.family
+        row_loss_and_grad = importlib.import_module(
+            fam.REFERENCE).row_loss_and_grad
         m = cell.model
         jobs = cell.traffic["jobs"]
         ranks = [int(j["rank"]) for j in jobs]
@@ -91,14 +107,15 @@ class Reference:
         alpha = float(cell.traffic["alpha"])
         L = int(m["num_hidden_layers"])
         key = weights.root_key(seed)
-        top = jax.jit(lambda k: weights.top_weights(k, m))(key)
-        one = jax.jit(lambda k, l: weights.layer_weights(k, m, l))
+        top = jax.jit(lambda k: fam.top_weights(k, m))(key)
+        one = jax.jit(lambda k, l: fam.layer_weights(k, m, l))
         layers = [one(key, jnp.int32(l)) for l in range(L)]
         # every job's adapters, zero-padded to the largest rank: padded
         # lanes add exact zeros to outputs and gradients alike
+        dims = fam.adapter_dims(m)
         pads = jax.jit(lambda k, l: [
             {t: {"A": pad_a(ab["A"], rmax), "B": pad_b(ab["B"], rmax)}
-             for t, ab in weights.job_adapter(k, m, j, l, r).items()}
+             for t, ab in weights.job_adapter(dims, k, j, l, r).items()}
             for j, r in enumerate(ranks)])
         per_layer = [pads(key, jnp.int32(l)) for l in range(L)]
         starts = [[per_layer[l][k] for l in range(L)]

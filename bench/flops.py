@@ -7,7 +7,8 @@ computed from the cell's shapes, and the chips' peaks.
   (``4 * d_in * d_out`` per token, no weight gradient), the head the
   same, and causal attention (``4 * heads * head_dim * (t + 1)`` for
   the token at position t in its document, three times over for the
-  forward and the input gradients of q, k and v);
+  forward and the input gradients of q, k and v), counted by the
+  configuration's family (``families/<family>.py``);
 * each job's adapters at its own rank: the forward ``x A``, ``(x A) B``,
   their two input gradients and two weight gradients,
   ``6 * r * (d_in + d_out)`` per token and projection.
@@ -25,8 +26,6 @@ from typing import Dict, Sequence
 
 import numpy as np
 
-import weights
-
 PEAKS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                      "peaks.json")
 BF16_BYTES = 2
@@ -43,45 +42,22 @@ def peaks(device_kind: str) -> Dict:
     return table[device_kind]
 
 
-def _dims(m: Dict):
-    d = m["hidden_size"]
-    H, KV = m["num_attention_heads"], m["num_key_value_heads"]
-    hd = m.get("head_dim", d // H)
-    return d, H, KV, hd, m["intermediate_size"], m["vocab_size"]
-
-
-def backbone_flops_per_token(m: Dict) -> float:
-    """Forward + input gradient of the projections and the head."""
-    d, H, KV, hd, f, V = _dims(m)
-    per_layer = d * H * hd + 2 * d * KV * hd + H * hd * d + 3 * d * f
-    return 4.0 * (m["num_hidden_layers"] * per_layer + d * V)
-
-
-def attention_flops(m: Dict, doc_lengths: Sequence[int]) -> float:
-    """Causal attention over documents of these lengths: forward (q k^T
-    and p v) and its input gradients, every layer."""
-    _, H, _, hd, _, _ = _dims(m)
-    n = np.asarray(doc_lengths, np.float64)
-    fwd = 4.0 * H * hd * float(np.sum(n * (n + 1) / 2))
-    return 3.0 * fwd * m["num_hidden_layers"]
-
-
-def lora_flops(m: Dict, tokens_by_rank: Dict[int, float]) -> float:
-    """Forward, input and weight gradients of every adapter, for
-    ``{rank: tokens}``."""
-    dims = weights.adapter_dims(m)
+def lora_flops(fam, m: Dict, tokens_by_rank: Dict[int, float]) -> float:
+    """Forward, input and weight gradients of every adapter of the
+    family *fam*, for ``{rank: tokens}``."""
+    dims = fam.adapter_dims(m)
     per_rank_token = 6.0 * sum(di + do for di, do in dims.values())
     return m["num_hidden_layers"] * per_rank_token * sum(
         r * t for r, t in tokens_by_rank.items())
 
 
-def lora_bytes(m: Dict, tokens_by_rank: Dict[int, float]) -> float:
+def lora_bytes(fam, m: Dict, tokens_by_rank: Dict[int, float]) -> float:
     """Least HBM traffic of the adapters' kernels: per projection, the
     forward reads x and writes the delta, the input gradient reads dy
     and writes dx, the weight gradient reads x and dy (bf16); every
     kernel reads A and B (bf16) and the weight gradient writes dA and
     dB (f32)."""
-    dims = weights.adapter_dims(m)
+    dims = fam.adapter_dims(m)
     total = 0.0
     for r, t in tokens_by_rank.items():
         for di, do in dims.values():
@@ -91,10 +67,11 @@ def lora_bytes(m: Dict, tokens_by_rank: Dict[int, float]) -> float:
     return m["num_hidden_layers"] * total
 
 
-def step_required_flops(m: Dict, traffic: Dict,
+def step_required_flops(fam, m: Dict, traffic: Dict,
                         doc_lengths: Sequence[int]) -> float:
-    """Required FLOPs of one step over its real tokens: *doc_lengths*
-    lists every row's document length, rows in job order."""
+    """Required FLOPs of one step over its real tokens, the family
+    *fam* counting its backbone and attention: *doc_lengths* lists
+    every row's document length, rows in job order."""
     n = np.asarray(doc_lengths, np.float64)
     rows = [int(j["rows"]) for j in traffic["jobs"]]
     offs = np.concatenate([[0], np.cumsum(rows)])
@@ -102,8 +79,8 @@ def step_required_flops(m: Dict, traffic: Dict,
     for k, j in enumerate(traffic["jobs"]):
         r = int(j["rank"])
         by_rank[r] = by_rank.get(r, 0.0) + float(n[offs[k]:offs[k + 1]].sum())
-    return (backbone_flops_per_token(m) * float(n.sum())
-            + attention_flops(m, n) + lora_flops(m, by_rank))
+    return (fam.backbone_flops_per_token(m) * float(n.sum())
+            + fam.attention_flops(m, n) + lora_flops(fam, m, by_rank))
 
 
 def lora_kernel_tokens(traffic: Dict) -> Dict[int, float]:

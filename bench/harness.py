@@ -114,7 +114,8 @@ def build(cell, seed: int, *, quantize=None,
     streams = traffic_gen.streams(tr, specs, cfg.vocab_size, seed)
     layout = SharedSuperModel(cfg, specs).layout
     params, adapters = weights.program_trees(
-        cfg, cell.model, [s.rank for s in specs], layout, seed)
+        cell.family, cfg, cell.model, [s.rank for s in specs], layout,
+        seed)
     rt = GroupRuntime.from_specs(cfg, specs, None, params=params,
                                  adapters=adapters, streams=streams,
                                  quantize=quantize)

@@ -15,7 +15,7 @@ def read(ctx):
     if not ctx.steps or not used:
         return None
     tok = flops.lora_kernel_tokens(ctx.cell.traffic)
-    m = ctx.cell.model
-    least = max(flops.lora_flops(m, tok) / ctx.peaks["bf16_flops_per_s"],
-                flops.lora_bytes(m, tok) / ctx.peaks["hbm_bytes_per_s"])
+    fam, m = ctx.cell.family, ctx.cell.model
+    least = max(flops.lora_flops(fam, m, tok) / ctx.peaks["bf16_flops_per_s"],
+                flops.lora_bytes(fam, m, tok) / ctx.peaks["hbm_bytes_per_s"])
     return 100.0 * least * ctx.steps / (used / 1e9)

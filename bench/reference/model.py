@@ -8,8 +8,8 @@ then RMSNorm and a SwiGLU MLP; a final RMSNorm and an untied head over
 the (sliced) vocabulary.  A job's adapter adds ``alpha / r * (x A) B``
 to each of the q, k, v and o projections.  The loss is next-token cross
 entropy over the positions a row's ``loss_mask`` keeps, each job's mean
-taken over its own tokens; AdamW (b1 0.9, b2 0.999, eps 1e-8, no weight
-decay) then steps each job with its own step count.
+taken over its own tokens; AdamW (``reference/adamw.py``) then steps
+each job with its own step count.
 
 Every matmul runs at float32 "highest" precision.  Weights arrive in
 the type they are served in and are widened to float32 exactly.  A call
@@ -142,11 +142,3 @@ def row_loss_and_grad(m: Dict, fp8: bool = False):
         return loss, count, grads
     return f
 
-
-def adamw(p, g, mu, nu, step, lr, b1=0.9, b2=0.999, eps=1e-8):
-    """One AdamW step (no weight decay) at 1-based *step*."""
-    mu = b1 * mu + (1 - b1) * g
-    nu = b2 * nu + (1 - b2) * g * g
-    mhat = mu / (1 - b1 ** step)
-    vhat = nu / (1 - b2 ** step)
-    return p - lr * mhat / (jnp.sqrt(vhat) + eps), mu, nu

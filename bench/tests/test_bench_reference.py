@@ -15,6 +15,7 @@ import catalog
 import check
 import traffic_gen
 import weights
+from families import dense_gqa
 from reference import model as ref
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -45,22 +46,25 @@ def test_program_trees_hold_the_reference_draws(config):
     specs = traffic_gen.job_specs(TRAFFIC, cfg.name)
     ranks = [s.rank for s in specs]
     layout = SharedSuperModel(cfg, specs).layout
-    params, adapters = weights.program_trees(cfg, m, ranks, layout, SEED)
+    fam = cell.family
+    params, adapters = weights.program_trees(fam, cfg, m, ranks, layout,
+                                             SEED)
     key = weights.root_key(SEED)
     seg = params["segments"][0]["0"]
     flat = {**seg["attn"], **seg["ffn"], "ln1": seg["ln1"], "ln2": seg["ln2"]}
     assert ("bq" in flat) == m["qkv_bias"]
-    top = weights.top_weights(key, m)
+    top = fam.top_weights(key, m)
     for n in ("embed", "head", "ln_f"):
         np.testing.assert_array_equal(params[n], top[n])
     ads = check.job_leaves(adapters, layout, ranks)
     for l in range(m["num_hidden_layers"]):
-        want = weights.layer_weights(key, m, l)
+        want = fam.layer_weights(key, m, l)
         assert set(want) == set(flat)
         for n, w in want.items():
             np.testing.assert_array_equal(flat[n][l], w)
         for k, r in enumerate(ranks):
-            for t, ab in weights.job_adapter(key, m, k, l, r).items():
+            for t, ab in weights.job_adapter(fam.adapter_dims(m), key, k,
+                                             l, r).items():
                 for part in ("A", "B"):
                     np.testing.assert_array_equal(ads[k][(l, t, part)],
                                                   ab[part])
@@ -74,9 +78,10 @@ def close(a, b):
 
 def tiny_model(key, m, rank):
     L = m["num_hidden_layers"]
-    top = weights.top_weights(key, m)
-    layers = [weights.layer_weights(key, m, l) for l in range(L)]
-    ads = [weights.job_adapter(key, m, 0, l, rank) for l in range(L)]
+    top = dense_gqa.top_weights(key, m)
+    layers = [dense_gqa.layer_weights(key, m, l) for l in range(L)]
+    ads = [weights.job_adapter(dense_gqa.adapter_dims(m), key, 0, l, rank)
+           for l in range(L)]
     return top, layers, ads
 
 

@@ -10,6 +10,7 @@ import pytest
 
 import catalog
 import flops
+from families import dense_gqa
 import traffic_gen
 import xtrace
 
@@ -26,33 +27,35 @@ def test_backbone_flops_hand_count():
     # per layer: q 8x8, k 8x4, v 8x4, o 8x8, gate/up 8x16, down 16x8
     per_layer = 64 + 32 + 32 + 64 + 3 * 128
     want = 4 * (3 * per_layer + 8 * 10)
-    assert flops.backbone_flops_per_token(TINY) == want
+    assert dense_gqa.backbone_flops_per_token(TINY) == want
 
 
 def test_attention_flops_hand_count():
     # one document of 3 tokens: positions attend to 1, 2, 3 keys;
     # q k^T and p v each 2*H*hd per key; forward + 2x for the gradients
     fwd = 2 * 2 * 2 * 4 * (1 + 2 + 3)
-    assert flops.attention_flops(TINY, [3]) == 3 * fwd * 3
+    assert dense_gqa.attention_flops(TINY, [3]) == 3 * fwd * 3
 
 
 @pytest.mark.parametrize("rank,tokens", [(1, 1), (4, 5), (16, 32)])
 def test_lora_flops_and_bytes_hand_count(rank, tokens):
     # q (8->8), k (8->4), v (8->4), o (8->8): sum(d_in + d_out) = 56
-    assert flops.lora_flops(TINY, {rank: tokens}) == \
+    assert flops.lora_flops(dense_gqa, TINY, {rank: tokens}) == \
         3 * 6 * rank * tokens * 56
     act = 2 * tokens * 3 * 56
     w = (3 * 2 + 4) * rank * 56
-    assert flops.lora_bytes(TINY, {rank: tokens}) == 3 * (act + w)
+    assert flops.lora_bytes(dense_gqa, TINY, {rank: tokens}) == \
+        3 * (act + w)
 
 
 def test_step_flops_counts_real_tokens_and_each_rank():
     traffic = {"jobs": [{"rank": 2, "rows": 1}, {"rank": 4, "rows": 2}]}
     lengths = [3, 5, 7]
-    want = (flops.backbone_flops_per_token(TINY) * 15
-            + flops.attention_flops(TINY, lengths)
-            + flops.lora_flops(TINY, {2: 3, 4: 12}))
-    assert flops.step_required_flops(TINY, traffic, lengths) == want
+    want = (dense_gqa.backbone_flops_per_token(TINY) * 15
+            + dense_gqa.attention_flops(TINY, lengths)
+            + flops.lora_flops(dense_gqa, TINY, {2: 3, 4: 12}))
+    assert flops.step_required_flops(dense_gqa, TINY, traffic,
+                                     lengths) == want
     assert flops.lora_kernel_tokens(
         dict(traffic, seq_len=8)) == {2: 8, 4: 16}
 
@@ -162,7 +165,7 @@ def test_breakdown_names_gaps_by_host_span(trace):
 
 def test_metric_readers_on_the_synthetic_trace(trace):
     lo, hi = xtrace.window(trace)
-    cell = catalog.Cell("c", 1, {"model": TINY},
+    cell = catalog.Cell("c", 1, {"family": "dense_gqa", "model": TINY},
                         {"jobs": [{"rank": 4, "rows": 1}], "seq_len": 8},
                         {}, [], [])
     ctx_kw = dict(trace=trace, lo=lo, hi=hi,
@@ -175,11 +178,12 @@ def test_metric_readers_on_the_synthetic_trace(trace):
         100 * ((1 - 400 / 900) + (1 - 500 / 900)) / 2)
     assert read("lora_kernel_ms_per_step") == pytest.approx(
         (100 + 0) / 2 / 1e6 / 2)
-    want_mfu = 100 * flops.step_required_flops(TINY, cell.traffic, [5]) \
+    want_mfu = 100 * flops.step_required_flops(dense_gqa, TINY,
+                                               cell.traffic, [5]) \
         * 2 / (900e-9 * 2 * 197e12)
     assert read("step_mfu") == pytest.approx(want_mfu)
-    least = max(flops.lora_flops(TINY, {4: 8}) / 197e12,
-                flops.lora_bytes(TINY, {4: 8}) / 819e9)
+    least = max(flops.lora_flops(dense_gqa, TINY, {4: 8}) / 197e12,
+                flops.lora_bytes(dense_gqa, TINY, {4: 8}) / 819e9)
     assert read("lora_kernel_roofline") == pytest.approx(
         100 * least * 2 / 100e-9)
 
